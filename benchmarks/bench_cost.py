@@ -28,29 +28,20 @@ must be fixed before jax initializes.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKERS = 8
 AGGS = (("d0", "sum"), ("d0", "count"), ("d0", "min"), ("d0", "max"))
 
 
 def run_worker(rows_per_worker: int, key_range: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={WORKERS}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_cost", "--worker",
+    return run_cpu_worker(
+        ["-m", "benchmarks.bench_cost", "--worker",
          "--rows-per-worker", str(rows_per_worker),
          "--key-range", str(key_range)],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        WORKERS)
 
 
 def _worker_main(argv) -> None:
@@ -65,7 +56,7 @@ def _worker_main(argv) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import timeit
+    from benchmarks.common import device_record, timeit
     from repro.core.context import DistContext
     from repro.core.table import Table as T
 
@@ -102,6 +93,7 @@ def _worker_main(argv) -> None:
     secs_cost = timeit(lambda: cost.collect().row_counts, warmup=1, iters=3)
 
     print("RESULT:" + json.dumps({
+        **device_record(),
         "rows": rows * WORKERS, "key_range": kr,
         "groups": int(np.asarray(c_out.global_rows())),
         "strategy": strategy,

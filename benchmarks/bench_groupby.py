@@ -14,11 +14,9 @@ platform must be fixed before jax initializes.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKERS = 8
 AGGS = (("d0", "sum"), ("d0", "mean"), ("d0", "var"), ("d1", "min"),
@@ -26,18 +24,11 @@ AGGS = (("d0", "sum"), ("d0", "mean"), ("d0", "var"), ("d1", "min"),
 
 
 def run_worker(strategy: str, rows_per_worker: int, key_range: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={WORKERS}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_groupby", "--worker",
+    return run_cpu_worker(
+        ["-m", "benchmarks.bench_groupby", "--worker",
          "--strategy", strategy, "--rows-per-worker", str(rows_per_worker),
          "--key-range", str(key_range)],
-        capture_output=True, text=True, env=env, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        WORKERS)
 
 
 def _worker_main(argv) -> None:
@@ -54,7 +45,7 @@ def _worker_main(argv) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import timeit
+    from benchmarks.common import device_record, timeit
     from repro.core.context import DistContext
     from repro.core.repartition import default_bucket_capacity
     from repro.data.synthetic import random_table
@@ -92,6 +83,7 @@ def _worker_main(argv) -> None:
     wire_bytes = WORKERS * WORKERS * bucket * row_bytes
     secs = timeit(lambda: fn()[0].row_counts, warmup=1, iters=3)
     print("RESULT:" + json.dumps({
+        **device_record(),
         "strategy": args.strategy, "rows": cap * WORKERS, "key_range": kr,
         "groups": groups, "seconds": secs, "received_rows": received,
         "overflow": overflow, "bucket": bucket, "wire_mb": wire_bytes / 1e6,

@@ -9,30 +9,20 @@ on real chips), and the per-worker collective bytes from the compiled HLO
 """
 from __future__ import annotations
 
-import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKER_COUNTS = [1, 2, 4, 8]
 OPS = ["join_hash", "join_sort", "union"]
 
 
 def run_worker(op: str, workers: int, rows_per_worker: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={workers}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.scaling_worker", "--op", op,
+    return run_cpu_worker(
+        ["-m", "benchmarks.scaling_worker", "--op", op,
          "--workers", str(workers), "--rows-per-worker",
          str(rows_per_worker)],
-        capture_output=True, text=True, env=env, timeout=900)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        workers)
 
 
 def bench_weak(rows_per_worker: int = 50_000) -> Table:

@@ -27,30 +27,21 @@ must be fixed before jax initializes.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKERS = 8
 
 
 def run_worker(rows_per_worker: int, num_clients: int,
                queries_per_client: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={WORKERS}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_serving", "--worker",
+    return run_cpu_worker(
+        ["-m", "benchmarks.bench_serving", "--worker",
          "--rows-per-worker", str(rows_per_worker),
          "--num-clients", str(num_clients),
          "--queries-per-client", str(queries_per_client)],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        WORKERS)
 
 
 def _worker_main(argv) -> None:
@@ -66,6 +57,7 @@ def _worker_main(argv) -> None:
     import jax
     import numpy as np
 
+    from benchmarks.common import device_record
     from repro.core.context import DistContext
     from repro.core.serving import ServingSession
     from repro.core.table import Table as T
@@ -121,6 +113,7 @@ def _worker_main(argv) -> None:
         for a, b in zip(cold_res, seq_res))
 
     print("RESULT:" + json.dumps({
+        **device_record(),
         "rows": n, "clients": args.num_clients,
         "queries": cold.num_queries,
         "cold_sequential": cold.to_dict(),

@@ -21,28 +21,19 @@ must be fixed before jax initializes.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKERS = 8
 
 
 def run_worker(rows_per_worker: int, stages: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={WORKERS}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_shuffle", "--worker",
+    return run_cpu_worker(
+        ["-m", "benchmarks.bench_shuffle", "--worker",
          "--rows-per-worker", str(rows_per_worker),
          "--stages", str(stages)],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        WORKERS)
 
 
 def _worker_main(argv) -> None:
@@ -58,7 +49,7 @@ def _worker_main(argv) -> None:
     import numpy as np
     from jax.sharding import PartitionSpec as P
 
-    from benchmarks.common import timeit
+    from benchmarks.common import device_record, timeit
     from repro.core import ops_dist as D
     from repro.core.context import DistContext
     from repro.core.table import Table as T
@@ -140,7 +131,7 @@ def _worker_main(argv) -> None:
                                                  results["ring"])
     out["wire_identical"] = (out["mono"]["wire_mb"] == out["staged"]["wire_mb"]
                              == out["ring"]["wire_mb"])
-    print("RESULT:" + json.dumps(out))
+    print("RESULT:" + json.dumps({**out, **device_record()}))
 
 
 def main(quick: bool = False):

@@ -20,29 +20,20 @@ must be fixed before jax initializes.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKERS = 8
 AGGS = (("d0", "sum"), ("d0", "count"), ("d0_r", "max"))
 
 
 def run_worker(rows_per_worker: int, key_range: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={WORKERS}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_sort_chain", "--worker",
+    return run_cpu_worker(
+        ["-m", "benchmarks.bench_sort_chain", "--worker",
          "--rows-per-worker", str(rows_per_worker),
          "--key-range", str(key_range)],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        WORKERS)
 
 
 def _worker_main(argv) -> None:
@@ -57,7 +48,7 @@ def _worker_main(argv) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import timeit
+    from benchmarks.common import device_record, timeit
     from repro.core.context import DistContext
     from repro.core.table import Table as T
 
@@ -120,6 +111,7 @@ def _worker_main(argv) -> None:
     secs_fused = timeit(lambda: fused.collect().row_counts, warmup=1, iters=3)
 
     print("RESULT:" + json.dumps({
+        **device_record(),
         "rows": cap * WORKERS, "key_range": kr,
         "groups": int(np.asarray(f_out.global_rows())),
         "identical": bool(identical),

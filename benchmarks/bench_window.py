@@ -20,11 +20,9 @@ must be fixed before jax initializes.
 from __future__ import annotations
 
 import json
-import os
-import subprocess
 import sys
 
-from benchmarks.common import Table
+from benchmarks.common import Table, run_cpu_worker
 
 WORKERS = 8
 FUNCS = ["rank", "dense_rank", "row_number", ("lag", "d0"), ("lead", "d0"),
@@ -32,18 +30,11 @@ FUNCS = ["rank", "dense_rank", "row_number", ("lag", "d0"), ("lead", "d0"),
 
 
 def run_worker(rows_per_worker: int, num_groups: int) -> dict:
-    env = dict(os.environ)
-    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={WORKERS}"
-    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
-    out = subprocess.run(
-        [sys.executable, "-m", "benchmarks.bench_window", "--worker",
+    return run_cpu_worker(
+        ["-m", "benchmarks.bench_window", "--worker",
          "--rows-per-worker", str(rows_per_worker),
          "--num-groups", str(num_groups)],
-        capture_output=True, text=True, env=env, timeout=1800)
-    if out.returncode != 0:
-        raise RuntimeError(out.stderr[-2000:])
-    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
-    return json.loads(line[7:])
+        WORKERS)
 
 
 def _worker_main(argv) -> None:
@@ -58,7 +49,7 @@ def _worker_main(argv) -> None:
     import jax
     import numpy as np
 
-    from benchmarks.common import timeit
+    from benchmarks.common import device_record, timeit
     from repro.core import ops_agg as A
     from repro.core.context import DistContext
     from repro.core.table import Table as T
@@ -121,6 +112,7 @@ def _worker_main(argv) -> None:
                        iters=3)
 
     print("RESULT:" + json.dumps({
+        **device_record(),
         "rows": n, "groups": args.num_groups,
         "naive_identical": bool(naive_ok),
         "presorted_identical": bool(pres_ok),

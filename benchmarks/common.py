@@ -1,10 +1,40 @@
-"""Shared benchmark utilities: timing + CSV emission."""
+"""Shared benchmark utilities: timing, CSV emission, CPU worker processes."""
 from __future__ import annotations
 
+import json
+import os
+import subprocess
+import sys
 import time
 
 import jax
 import numpy as np
+
+
+def run_cpu_worker(argv: list[str], devices: int) -> dict:
+    """Run ``python <argv>`` on ``devices`` virtual CPU devices and return
+    the JSON of its last ``RESULT:`` line.
+
+    These workers are CPU runs by construction: the device count must be
+    fixed before JAX starts, and ``JAX_PLATFORMS=cpu`` keeps them off any
+    accelerator the host has (a chip serves one process at a time)."""
+    env = dict(os.environ)
+    env["XLA_FLAGS"] = f"--xla_force_host_platform_device_count={devices}"
+    env["JAX_PLATFORMS"] = "cpu"
+    env["PYTHONPATH"] = "src:" + env.get("PYTHONPATH", "")
+    out = subprocess.run([sys.executable, *argv], capture_output=True,
+                         text=True, env=env, timeout=1800)
+    if out.returncode != 0:
+        raise RuntimeError(out.stderr[-2000:])
+    line = [l for l in out.stdout.splitlines() if l.startswith("RESULT:")][-1]
+    return json.loads(line[7:])
+
+
+def device_record() -> dict:
+    """The devices a worker ran on, as JAX reports them."""
+    dev = jax.devices()[0]
+    return {"platform": dev.platform, "device_kind": dev.device_kind,
+            "device_count": jax.device_count()}
 
 
 def timeit(fn, *args, warmup: int = 2, iters: int = 5) -> float:

@@ -22,7 +22,7 @@ def main():
 
     import jax
 
-    from benchmarks.common import timeit
+    from benchmarks.common import device_record, timeit
     from repro.core.context import DistContext
     from repro.data.synthetic import random_table
 
@@ -55,6 +55,7 @@ def main():
 
     t = timeit(fn, warmup=2, iters=5)
     print("RESULT:" + json.dumps({
+        **device_record(),
         "op": args.op, "workers": p, "rows_per_worker": args.rows_per_worker,
         "total_rows": n, "seconds": t,
         "rows_per_second": n / t,

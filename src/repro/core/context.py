@@ -52,7 +52,7 @@ from repro.core.repartition import (Partitioning, RangePartitioning,
 from repro.core.stats import TableStats
 from repro.core.table import KEY_DTYPES, Table
 from repro.kernels import ops as kops
-from repro.utils import ceil_div
+from repro.utils import ceil_div, make_mesh, shard_map
 
 
 @jax.tree_util.register_pytree_node_class
@@ -238,7 +238,7 @@ class DistContext:
                  retry_policy: FLT.RetryPolicy | None = None,
                  validate: bool | None = None):
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), (axis_name,))
+            mesh = make_mesh((jax.device_count(),), (axis_name,))
         assert axis_name in mesh.axis_names, (axis_name, mesh.axis_names)
         self.mesh = mesh
         self.axis_name = axis_name
@@ -346,18 +346,23 @@ class DistContext:
         """
         if t.stats is not None:
             return t
-        p, c = t.num_shards, t.local_capacity
+        c = t.local_capacity
         counts = np.asarray(t.row_counts)
         rows = int(counts.sum())
         names = tuple(k for k, v in sorted(t.columns.items())
                       if v.ndim == 1 and v.dtype in KEY_DTYPES)
 
         def sweep(cols, rc):
-            idx = jnp.arange(p * c)
-            valid = (idx % c) < rc[idx // c]
-            return ST.sketch_columns(cols, valid, names)
+            # per shard: a Pallas kernel (the hash) cannot be partitioned
+            # automatically, so the sweep runs inside shard_map
+            valid = jnp.arange(c) < rc[0]
+            return ST.sketch_columns(cols, valid, names,
+                                     axis_name=self.axis_name)
 
-        sk = jax.jit(sweep)({n: t.columns[n] for n in names}, t.row_counts)
+        axis = P(self.axis_name)
+        sk = jax.jit(shard_map(sweep, mesh=self.mesh, in_specs=(axis, axis),
+                               out_specs=P()))(
+            {n: t.columns[n] for n in names}, t.row_counts)
         cols = []
         for n in names:
             filled, lo, hi = sk[n]
@@ -384,8 +389,6 @@ class DistContext:
     # -- shard_map plumbing ---------------------------------------------------
     def _make_global(self, body: Callable) -> Callable:
         """Wrap a per-shard `body(*tables) -> (Table, stats)` in shard_map."""
-        from repro.utils import shard_map
-
         axis = self.axis_name
 
         def local_fn(*local_tabs):

@@ -32,7 +32,6 @@ from repro.core import ops_local as L
 from repro.core.repartition import (ShuffleStats, _counts_carrier,
                                     repartition, zero_shuffle_stats)
 from repro.core.table import Table
-from repro.utils import axis_size
 
 
 def _row_pid(table: Table, key_columns: Sequence[str], p: int, seed: int):
@@ -67,7 +66,7 @@ def _shuffle(table: Table, keys: Sequence[str], *, axis_name: str,
     """
     from repro.core import stats as S
 
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     rb = _row_bytes(table)
     if stages is None and not skip:
         stages = S.pick_stages(p * p * bucket_capacity * rb, bucket_capacity)
@@ -156,7 +155,7 @@ def _range_align_pid(table: Table, anchor: Table, keys: Sequence[str], *,
     rows beyond the global max land on the last shard (where, for a join,
     they meet no anchor rows anyway).
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     c = table.capacity
     local_max = _lex_max_key_tuple(anchor, keys)
     gathered = [jax.lax.all_gather(m, axis_name) for m in local_max]  # (p,)
@@ -272,7 +271,7 @@ def dist_limit(table: Table, n: int, *, axis_name: str,
     ordered key ranges). The report record keeps Limit attributed in the
     wire accounting at 0 bytes.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if report is not None:
         report.append({"op": "limit", "elided": True,
                        "row_bytes": _row_bytes(table), "bucket": 0,
@@ -548,7 +547,7 @@ def dist_window(
     order_l = [order_by] if isinstance(order_by, str) else list(order_by)
     keys = by_l + order_l
     pairs = A.normalize_funcs(funcs)
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
 
     if skip_shuffle:
         t2, st = _shuffle(table, keys, axis_name=axis_name,
@@ -602,7 +601,7 @@ def _lex_splitter_pids(table: Table, by: Sequence[str], *, axis_name: str,
     a short comparison cascade, which sidesteps packing multi-key tuples
     into a single wide integer (no uint64 without x64 on this stack).
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     valid = table.valid_mask()
     c = table.capacity
     stride = max(1, c // samples_per_shard)

@@ -43,7 +43,6 @@ from repro.core import faults as FLT
 from repro.core.table import Table
 from repro.core.ops_local import compact
 from repro.kernels import ops as kops
-from repro.utils import axis_size
 
 
 class ShuffleStats(NamedTuple):
@@ -171,7 +170,7 @@ def _ring_exchange(buf: jax.Array, axis_name: str) -> jax.Array:
     bucket, no collective). A comparison strategy for the staged dense
     collective: maximally decomposed, so `stages` does not subdivide it.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     if p == 1:
         return buf
     idx = jax.lax.axis_index(axis_name)
@@ -253,12 +252,15 @@ def _shuffle_fault(bucket_capacity: int, stages: int,
 
 def _counts_carrier(table: Table) -> str | None:
     """The column whose exchange carries the per-bucket send counts: the
-    first (sorted) 4-byte column — the int32 counts bitcast losslessly into
-    its dtype and ride a prepended capacity slot of its FIRST chunk, so no
-    separate counts collective is needed. None when no column qualifies
-    (the separate-collective fallback)."""
+    first (sorted) 4-byte integer column — the int32 counts bitcast
+    losslessly into its dtype and ride a prepended capacity slot of its
+    FIRST chunk, so no separate counts collective is needed. Never a float
+    column: a count below 2^23 bitcasts to a subnormal float, which the TPU
+    flushes to zero, dropping the whole bucket. None when no column
+    qualifies (the separate-collective fallback)."""
     for name in table.column_names:
-        if table.columns[name].dtype.itemsize == 4:
+        dtype = table.columns[name].dtype
+        if dtype.itemsize == 4 and jnp.issubdtype(dtype, jnp.integer):
             return name
     return None
 
@@ -280,7 +282,7 @@ def repartition(
     shuffle_mode)`` is bit-identical — same recv layout, same overflow
     accounting, same compacted row order.
     """
-    p = axis_size(axis_name)
+    p = jax.lax.axis_size(axis_name)
     c = table.capacity
     cb = bucket_capacity
     valid = table.valid_mask()

@@ -219,16 +219,17 @@ def size_output(rows: float, p: int, factor: float = 1.0) -> int:
 # --------------------------------------------------------------------------
 
 
-def _sketch_one(col: jax.Array, valid: jax.Array):
+def _sketch_one(col: jax.Array, valid: jax.Array, axis_name=None):
     """(occupied-bitmap-count, min, max) of a 1-D key column as f32/i32
-    scalars — traced; the host wrapper turns them into ColumnStats."""
+    scalars — traced; the host wrapper turns them into ColumnStats.
+    Inside a shard_map over ``axis_name`` each shard sketches its own rows
+    and the bitmaps and bounds merge across shards."""
     from repro.kernels import ops as kops
 
     h = kops.hash32(col, seed=5)
     b = jnp.where(valid, (h % jnp.uint32(SKETCH_BUCKETS)).astype(jnp.int32),
                   SKETCH_BUCKETS)
     occ = jnp.zeros((SKETCH_BUCKETS,), jnp.int32).at[b].set(1, mode="drop")
-    filled = jnp.sum(occ)
     if jnp.issubdtype(col.dtype, jnp.floating):
         lo_s, hi_s = jnp.inf, -jnp.inf
     else:
@@ -236,7 +237,11 @@ def _sketch_one(col: jax.Array, valid: jax.Array):
         lo_s, hi_s = info.max, info.min
     lo = jnp.min(jnp.where(valid, col, jnp.asarray(lo_s, col.dtype)))
     hi = jnp.max(jnp.where(valid, col, jnp.asarray(hi_s, col.dtype)))
-    return filled, lo, hi
+    if axis_name is not None:
+        occ = jax.lax.pmax(occ, axis_name)
+        lo = jax.lax.pmin(lo, axis_name)
+        hi = jax.lax.pmax(hi, axis_name)
+    return jnp.sum(occ), lo, hi
 
 
 def linear_count(filled: int, rows: float,
@@ -251,10 +256,11 @@ def linear_count(filled: int, rows: float,
 
 
 def sketch_columns(columns: Mapping[str, jax.Array], valid: jax.Array,
-                   names: Sequence[str]):
+                   names: Sequence[str], axis_name=None):
     """Traced sketch of ``names`` columns under ``valid``: name ->
-    (filled, lo, hi). Composable under jit; host wrappers finish it."""
-    return {n: _sketch_one(columns[n], valid) for n in names}
+    (filled, lo, hi). Composable under jit; host wrappers finish it.
+    ``axis_name``: merge the per-shard sketches over that mesh axis."""
+    return {n: _sketch_one(columns[n], valid, axis_name) for n in names}
 
 
 def analyze_table(table) -> TableStats:

@@ -2,12 +2,14 @@
 
 Cylon's hash-partition needs per-destination row counts before building send
 buffers. Scatter-add (the CPU/GPU idiom) is serialized on TPU; the native
-formulation is a one-hot compare + reduction, which the compiler maps onto
-dense vector ops (and onto the MXU via one_hot @ ones when P is large).
+formulation is a compare + reduction per bucket over dense (rows, 128)
+blocks. P is the shard count, so the per-bucket loop is short.
 
-Grid walks row-blocks; each step accumulates its block's counts into the
-single (1, P) output block (revisited across the grid — Pallas keeps it
-resident in VMEM, so HBM sees one read of ids and one write of P counts).
+Grid walks row-blocks; each step counts its block once per bucket and
+accumulates into the single (1, P) output block (revisited across the
+grid — Pallas keeps it resident in VMEM, so HBM sees one read of ids and
+one write of P counts). Blocks stay 2-D inside the kernel: Mosaic rejects
+flattening a (rows, 128) block to a column.
 """
 from __future__ import annotations
 
@@ -30,12 +32,17 @@ def _hist_kernel(ids_ref, o_ref, *, num_buckets: int):
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ids = ids_ref[...].reshape(-1)  # (BLOCK_ROWS*LANES,)
+    ids = ids_ref[...]  # (BLOCK_ROWS, LANES), kept 2-D for Mosaic
     buckets = jax.lax.broadcasted_iota(jnp.int32, (1, num_buckets), 1)
-    # one-hot (rows, P) summed over rows -> (1, P); invalid ids (< 0, e.g.
-    # padding) match no bucket.
-    onehot = (ids[:, None] == buckets).astype(jnp.int32)
-    o_ref[...] += jnp.sum(onehot, axis=0, keepdims=True)
+
+    # one masked count per bucket (P is the shard count: small); invalid
+    # ids (< 0, e.g. padding) match no bucket
+    def count(b, acc):
+        n = jnp.sum((ids == b).astype(jnp.int32))
+        return acc + jnp.where(buckets == b, n, 0)
+
+    o_ref[...] += jax.lax.fori_loop(0, num_buckets, count,
+                                    jnp.zeros((1, num_buckets), jnp.int32))
 
 
 @functools.partial(jax.jit, static_argnames=("num_buckets", "interpret"))

@@ -116,10 +116,10 @@ def segment_reduce(
     XLA scatter-reduce; ``use_kernel=False`` forces that path (the
     bit-identical oracle the tests sweep against).
 
-    Auto (``use_kernel=None``) prefers the kernel wherever it actually
-    runs AS a kernel; under interpret mode (no TPU — tests, CPU CI) the
-    emulated multi-tile one-hot is far slower than XLA scatter, so auto
-    only takes the kernel path for single-tile segment counts there.
+    Auto (``use_kernel=None``) takes the kernel only for single-tile
+    segment counts (<= MAX_SEGMENTS), on every backend: each further
+    segment tile re-reads every row, so the kernel's cost grows as
+    rows x segments, and a large groupby goes to the XLA scatter.
 
     Resolution happens HERE, outside the jit: :func:`oracle_scope` (the
     recovery ladder) overrides any choice to the XLA path, and an armed
@@ -131,8 +131,7 @@ def segment_reduce(
         values.shape, seg_ids.shape)
     shape_ok = values.ndim == 1 and values.dtype in (jnp.float32, jnp.int32)
     if use_kernel is None:
-        use_kernel = shape_ok and (num_segments <= MAX_SEGMENTS
-                                   or not interpret_mode())
+        use_kernel = shape_ok and num_segments <= MAX_SEGMENTS
     elif use_kernel and not shape_ok:
         raise ValueError(
             f"segment_reduce kernel needs 1-D f32/i32 values; got "

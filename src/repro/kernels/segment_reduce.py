@@ -12,12 +12,14 @@ The grid is 2-D: ``(segment tiles, row blocks)``. Each step folds one row
 block's partials into the current (1, SEG_TILE)-wide slice of the output;
 the row axis is the *inner* grid dimension, so a given output tile stays
 VMEM-resident across all of its row steps (HBM sees the rows once per
-segment tile and one write per output tile). ``MAX_SEGMENTS`` is the
-per-tile width budget — the (rows_block, SEG_TILE) one-hot that must fit
-in VMEM — not a limit on the total segment count: larger ``num_segments``
-simply adds segment tiles, each comparing against its own offset window of
-the segment id space. The XLA scatter path (``kernels/ops.py``,
-``use_kernel=False``) remains the oracle/fallback for N-D payloads.
+segment tile and one write per output tile). Within a step, each 128-row
+line of the block builds its own (128, SEG_TILE) one-hot from the
+transposed block. ``MAX_SEGMENTS`` is the per-tile width budget, not a
+limit on the total segment count: larger ``num_segments`` simply adds
+segment tiles, each comparing against its own offset window of the
+segment id space — but every tile re-reads all rows, so the cost grows as
+rows x segments and ``kernels/ops.py`` routes counts above it to the XLA
+scatter, which is also the oracle/fallback for N-D payloads.
 """
 from __future__ import annotations
 
@@ -31,7 +33,7 @@ from repro.kernels import ref
 from repro.utils import interpret_mode, round_up
 
 LANES = 128
-BLOCK_ROWS = 8  # (8, 128) = 1024 rows per grid step; (1024, G) one-hot fits VMEM
+BLOCK_ROWS = 8  # (8, 128) = 1024 rows per grid step
 # per-tile segment width (VMEM budget for the one-hot), NOT a global cap:
 # num_segments beyond it tiles the segment axis in the second grid dim
 MAX_SEGMENTS = 1024
@@ -48,30 +50,33 @@ def _seg_kernel(seg_ref, val_ref, o_ref, *, op: str, seg_tile: int):
     def _init():
         o_ref[...] = jnp.full_like(o_ref, init)
 
-    seg = seg_ref[...].reshape(-1)  # (BLOCK_ROWS*LANES,)
-    val = val_ref[...].reshape(-1)
+    seg, val = seg_ref[...], val_ref[...]  # (BLOCK_ROWS, LANES)
+    # Mosaic cannot flatten a (rows, 128) block into a column, but it can
+    # transpose it: column r of seg.T is row r of the block, with its 128
+    # rows of the table on sublanes — the one-hot's row axis.
+    seg_t, val_t = seg.T, val.T  # (LANES, BLOCK_ROWS)
     # this tile covers segment ids [seg_base, seg_base + seg_tile)
     buckets = jax.lax.broadcasted_iota(jnp.int32, (1, seg_tile), 1) + seg_base
-    onehot = seg[:, None] == buckets  # (rows, tile); padding (-1) matches none
-    if op == "sum" and val.dtype == jnp.float32:
-        # MXU path: (1, rows) @ (rows, tile)
-        o_ref[...] += jnp.dot(val[None, :], onehot.astype(jnp.float32),
-                              preferred_element_type=jnp.float32)
-    elif op == "sum":
-        o_ref[...] += jnp.sum(jnp.where(onehot, val[:, None], init),
-                              axis=0, keepdims=True)
-    elif op == "min":
-        o_ref[...] = jnp.minimum(
-            o_ref[...],
-            jnp.min(jnp.where(onehot, val[:, None], init), axis=0,
-                    keepdims=True))
-    elif op == "max":
-        o_ref[...] = jnp.maximum(
-            o_ref[...],
-            jnp.max(jnp.where(onehot, val[:, None], init), axis=0,
-                    keepdims=True))
-    else:
-        raise ValueError(op)
+    acc = o_ref[...]
+    for r in range(seg.shape[0]):
+        # (128, tile) one-hot; padding (-1) matches no bucket
+        onehot = seg_t[:, r:r + 1] == buckets
+        if op == "sum" and val.dtype == jnp.float32:
+            # MXU path: (1, 128) @ (128, tile)
+            acc += jnp.dot(val[r:r + 1, :], onehot.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+            continue
+        part = jnp.where(onehot, val_t[:, r:r + 1], init)
+        if op == "sum":
+            acc += jnp.sum(part, axis=0, keepdims=True)
+        elif op == "min":
+            acc = jnp.minimum(acc, jnp.min(part, axis=0, keepdims=True))
+        elif op == "max":
+            acc = jnp.maximum(acc, jnp.max(part, axis=0, keepdims=True))
+        else:
+            raise ValueError(op)
+    o_ref[...] = acc
 
 
 @functools.partial(jax.jit,

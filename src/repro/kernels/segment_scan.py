@@ -7,16 +7,16 @@ segmented running sums, ``cummax`` a running max — all over contiguous
 per-group runs of rows.
 
 The kernel formulation mirrors kernels/segment_reduce.py's one-hot idiom,
-tiled along the segment-sorted row axis: the grid walks row blocks in
-order, and each block materializes the (BLOCK, BLOCK) *triangular same-
-segment* mask — ``mask[i, j] = (j < i) & (seg[j] == seg[i])`` — so the
-exclusive scan of a block is one masked reduction over the j axis (an MXU
-matmul for f32 sums, a VPU min/max otherwise). TPU grid steps execute
-sequentially and output blocks with a constant index map stay VMEM-
-resident, so the cross-block carry (the running value and segment id at
-the previous block's last row) lives in two (1, 1) output refs revisited
-by every step — the same persistence contract segment_reduce relies on
-for its output tiles.
+tiled along the segment-sorted row axis: the grid walks (8, 128) row blocks
+in order, and for each 128-row line of a block it materializes the
+(128, 128) *triangular same-segment* mask — ``mask[j, i] = (j < i) &
+(seg[j] == seg[i])`` — so the line's exclusive scan is one masked reduction
+over the j axis (an MXU matmul for f32 sums, a VPU min/max otherwise). The
+j axis comes from the transposed block: Mosaic cannot flatten a block into
+a column. Lines are scanned in order, and TPU grid steps execute
+sequentially, so the carry (the running value and segment id of the last
+row scanned) threads every line of every block; across grid steps it lives
+in VMEM scratch, broadcast over the lanes.
 
 Requirements: segment ids form contiguous runs (non-decreasing, as
 produced by sort + cumsum-of-boundaries), with -1 allowed as trailing
@@ -33,15 +33,14 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref
 from repro.utils import interpret_mode, round_up
 
 LANES = 128
 BLOCK_ROWS = 8
-#: rows per grid step — the (BLOCK, BLOCK) triangular mask is the VMEM
-#: budget (1 MiB of bool + a 4 MiB f32 one-hot on the matmul path), the
-#: same block budget segment_reduce spends on its one-hot.
+#: rows per grid step; each of its 8 lines builds one (128, 128) mask
 BLOCK = BLOCK_ROWS * LANES  # 1024
 
 OPS = ("sum", "min", "max")
@@ -58,42 +57,44 @@ def _scan_kernel(seg_ref, val_ref, o_ref, cval_ref, cseg_ref, *,
         # -2 matches no real segment id (>= 0) and no -1 padding
         cseg_ref[...] = jnp.full_like(cseg_ref, -2)
 
-    seg = seg_ref[...].reshape(-1)  # (BLOCK,)
-    val = val_ref[...].reshape(-1)
-    n = seg.shape[0]
-    ii = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0)
-    jj = jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
-    # strict triangle: row i's EXCLUSIVE prefix within its segment run
-    mask = (jj < ii) & (seg[None, :] == seg[:, None])
-    if op == "sum" and val.dtype == jnp.float32:
-        # MXU path: (n, n) @ (n, 1)
-        excl = jnp.dot(mask.astype(jnp.float32), val[:, None],
-                       preferred_element_type=jnp.float32).reshape(-1)
-    elif op == "sum":
-        excl = jnp.sum(jnp.where(mask, val[None, :], jnp.zeros_like(init)),
-                       axis=1)
-    elif op == "min":
-        excl = jnp.min(jnp.where(mask, val[None, :], init), axis=1)
-    else:  # max
-        excl = jnp.max(jnp.where(mask, val[None, :], init), axis=1)
-
-    # fold the previous blocks' carry into rows continuing its segment
-    cont = seg == cseg_ref[0, 0]
-    carry = jnp.where(cont, cval_ref[0, 0], init)
-    if op == "sum":
-        excl = excl + carry
-        incl = excl + val
-    elif op == "min":
-        excl = jnp.minimum(excl, carry)
-        incl = jnp.minimum(excl, val)
-    else:
-        excl = jnp.maximum(excl, carry)
-        incl = jnp.maximum(excl, val)
-
-    out = incl if inclusive else excl
-    o_ref[...] = out.reshape(o_ref.shape)
-    cval_ref[0, 0] = incl[n - 1]
-    cseg_ref[0, 0] = seg[n - 1]
+    seg, val = seg_ref[...], val_ref[...]  # (BLOCK_ROWS, LANES)
+    # transposed copies put one block row's 128 table rows on sublanes: the
+    # predecessor axis j of the mask (Mosaic cannot flatten the block)
+    seg_t, val_t = seg.T, val.T
+    jj = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 0)
+    ii = jax.lax.broadcasted_iota(jnp.int32, (LANES, LANES), 1)
+    # the carry: running value and segment id of the last row scanned so
+    # far, broadcast over the lanes
+    cval, cseg = cval_ref[...], cseg_ref[...]  # (1, LANES)
+    for r in range(seg.shape[0]):
+        s_row, v_row = seg[r:r + 1, :], val[r:r + 1, :]  # (1, LANES)
+        # mask[j, i]: row j strictly precedes row i in i's segment run
+        mask = (jj < ii) & (seg_t[:, r:r + 1] == s_row)
+        if op == "sum" and val.dtype == jnp.float32:
+            # MXU path: (1, 128) @ (128, 128)
+            excl = jnp.dot(v_row, mask.astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+        else:
+            part = jnp.where(mask, val_t[:, r:r + 1], init)
+            excl = {"sum": jnp.sum, "min": jnp.min, "max": jnp.max}[op](
+                part, axis=0, keepdims=True)
+        # fold the carry into rows continuing its segment
+        carry = jnp.where(s_row == cseg, cval, init)
+        if op == "sum":
+            excl = excl + carry
+            incl = excl + v_row
+        elif op == "min":
+            excl = jnp.minimum(excl, carry)
+            incl = jnp.minimum(excl, v_row)
+        else:
+            excl = jnp.maximum(excl, carry)
+            incl = jnp.maximum(excl, v_row)
+        o_ref[r:r + 1, :] = incl if inclusive else excl
+        cval = jnp.broadcast_to(incl[:, LANES - 1:], cval.shape)
+        cseg = jnp.broadcast_to(s_row[:, LANES - 1:], cseg.shape)
+    cval_ref[...] = cval
+    cseg_ref[...] = cseg
 
 
 @functools.partial(jax.jit,
@@ -126,18 +127,18 @@ def segment_scan_tiles(
     valp = jnp.zeros((n_pad,), values.dtype).at[:n].set(values) \
         .reshape(n_pad // LANES, LANES)
     grid = (n_pad // BLOCK,)
-    out, _, _ = pl.pallas_call(
+    out = pl.pallas_call(
         functools.partial(_scan_kernel, op=op, inclusive=inclusive),
-        out_shape=[jax.ShapeDtypeStruct((n_pad // LANES, LANES),
-                                        values.dtype),
-                   jax.ShapeDtypeStruct((1, 1), values.dtype),  # carry val
-                   jax.ShapeDtypeStruct((1, 1), jnp.int32)],    # carry seg
+        out_shape=jax.ShapeDtypeStruct((n_pad // LANES, LANES), values.dtype),
         grid=grid,
         in_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda s: (s, 0)),
                   pl.BlockSpec((BLOCK_ROWS, LANES), lambda s: (s, 0))],
-        out_specs=[pl.BlockSpec((BLOCK_ROWS, LANES), lambda s: (s, 0)),
-                   pl.BlockSpec((1, 1), lambda s: (0, 0)),
-                   pl.BlockSpec((1, 1), lambda s: (0, 0))],
+        out_specs=pl.BlockSpec((BLOCK_ROWS, LANES), lambda s: (s, 0)),
+        scratch_shapes=[pltpu.VMEM((1, LANES), values.dtype),  # carry val
+                        pltpu.VMEM((1, LANES), jnp.int32)],    # carry seg
+        # the carry threads the row blocks in order
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
     )(segp, valp)
     return out.reshape(n_pad)[:n]

@@ -41,6 +41,7 @@ from repro.models.factory import build_model
 from repro.roofline import analysis as RA
 from repro.train.optimizer import OptConfig
 from repro.train import steps as ST
+from repro.utils import use_compile_cache
 
 
 def _named(mesh, spec_tree, shape_tree):
@@ -273,8 +274,7 @@ def main():
                     help="merge into existing --out instead of overwriting")
     args = ap.parse_args()
 
-    cache_dir = os.environ.get("JAX_CACHE_DIR", "/tmp/jax_cache")
-    jax.config.update("jax_compilation_cache_dir", cache_dir)
+    use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
 
     meshes = ["single", "multi"] if args.mesh == "both" else [args.mesh]

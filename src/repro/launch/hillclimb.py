@@ -16,6 +16,7 @@ from repro.configs import get_config
 from repro.launch.dryrun import compile_cell, roofline_cell
 from repro.launch.mesh import make_production_mesh
 from repro.roofline import analysis as RA
+from repro.utils import use_compile_cache
 
 
 def _measure(cfg, shape, mesh, *, microbatches=None):
@@ -77,7 +78,7 @@ def main():
     ap.add_argument("--variants", default=None,
                     help="comma-separated subset of variant names")
     args = ap.parse_args()
-    jax.config.update("jax_compilation_cache_dir", "/tmp/jax_cache")
+    use_compile_cache()
     jax.config.update("jax_persistent_cache_min_compile_time_secs", 5)
 
     mesh = make_production_mesh()

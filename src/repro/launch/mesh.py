@@ -10,11 +10,13 @@ from __future__ import annotations
 
 import jax
 
+from repro.utils import make_mesh
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_local_mesh(model: int = 1, pod: int = 1):
@@ -23,5 +25,5 @@ def make_local_mesh(model: int = 1, pod: int = 1):
     data = n // (model * pod)
     assert data * model * pod == n, (n, model, pod)
     if pod > 1:
-        return jax.make_mesh((pod, data, model), ("pod", "data", "model"))
-    return jax.make_mesh((data, model), ("data", "model"))
+        return make_mesh((pod, data, model), ("pod", "data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -34,7 +34,7 @@ from repro.core.stats import pick_stages
 from repro.models.common import (
     DATA_AXIS, MODEL_AXIS, ModelConfig, ShardingRules)
 from repro.models.layers import _dense
-from repro.utils import axis_size, ceil_div, round_up, shard_map
+from repro.utils import ceil_div, round_up, shard_map
 
 
 def padded_experts(cfg: ModelConfig, model_size: int) -> int:
@@ -117,7 +117,7 @@ def _dispatch_compute_combine(p, xt, cfg: ModelConfig, e_pad: int,
     buf = jnp.where(sel, xt[jnp.clip(tok_idx, 0, t - 1)], 0)  # (E, cap, d)
 
     if axis is not None:
-        m = axis_size(axis)
+        m = jax.lax.axis_size(axis)
         e_loc = e_pad // m
         # (E, cap, d) -> (M, E_loc*cap, d) -> exchange -> (E_loc, M*cap, d)
         sendb = buf.reshape(m, e_loc * cap, d)

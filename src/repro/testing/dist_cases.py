@@ -18,6 +18,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import utils as U
+
 
 def _ctx(axis="shuffle"):
     from repro.core.context import DistContext
@@ -575,7 +577,7 @@ def case_moe_ep():
     from repro.models.moe import init_moe, moe_fwd
     from repro.models.common import ShardingRules
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = U.make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(arch="m", family="moe", num_layers=1, d_model=32,
                       num_heads=4, num_kv_heads=4, d_ff=0, vocab_size=64,
                       moe_num_experts=8, moe_top_k=2, moe_num_shared=1,
@@ -604,7 +606,7 @@ def case_moe_decode_psum():
     from repro.models.common import ModelConfig, ShardingRules
     from repro.models.moe import init_moe, moe_fwd
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = U.make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(arch="m", family="moe", num_layers=1, d_model=32,
                       num_heads=4, num_kv_heads=4, d_ff=0, vocab_size=64,
                       moe_num_experts=8, moe_top_k=2, moe_num_shared=0,
@@ -623,7 +625,7 @@ def case_flash_decode_shard():
     from repro.models import layers as NN
     from repro.models.common import ModelConfig
 
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = U.make_mesh((2, 4), ("data", "model"))
     cfg = ModelConfig(arch="d", family="dense", num_layers=1, d_model=64,
                       num_heads=8, num_kv_heads=2, d_ff=64, vocab_size=64,
                       head_dim=8, decode_seq_shard=True)
@@ -658,7 +660,7 @@ def case_compress_pod():
     from repro.train.steps import (init_train_state, make_train_step,
                                    train_state_specs)
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = U.make_mesh((2, 2, 2), ("pod", "data", "model"))
     cfg = ModelConfig(arch="t", family="dense", num_layers=2, d_model=32,
                       num_heads=4, num_kv_heads=2, d_ff=64, vocab_size=128,
                       head_dim=8, remat="none")
@@ -707,7 +709,7 @@ def case_elastic_restore():
     for name, shape, axes in [("a", (4, 2), ("data", "model")),
                               ("b", (2, 4), ("data", "model")),
                               ("c", (8, 1), ("data", "model"))]:
-        mesh = jax.make_mesh(shape, axes)
+        mesh = U.make_mesh(shape, axes)
         model = build_model(cfg, mesh)
         with mesh:
             if state0 is None:

@@ -200,12 +200,12 @@ def make_train_step(model: Model, ocfg: OptConfig, *, microbatches: int = 1,
     def step_fn(state: TrainState, batch):
         ef_specs = jax.tree.map(lambda e: P(POD_AXIS), state.ef)
         batch_in = jax.tree.map(lambda x: P(POD_AXIS), batch)
-        from repro.utils import shard_map as _sm  # compat wrapper
-        grads, new_ef, metrics = _sm(
+        from repro.utils import shard_map
+        grads, new_ef, metrics = shard_map(
             pod_body, mesh=mesh,
             in_specs=(P(), ef_specs, batch_in),
             out_specs=(P(), ef_specs, P()),
-            axis_names={POD_AXIS}, check_rep=False,
+            axis_names={POD_AXIS},
         )(state.params, state.ef, batch)
         params, opt, om = apply_updates(state.params, grads, state.opt, ocfg)
         return TrainState(params, opt, state.step + 1, new_ef), \

@@ -2,9 +2,12 @@
 from __future__ import annotations
 
 import functools
+import os
+from pathlib import Path
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import AxisType, NamedSharding
 
 
 @functools.cache
@@ -15,6 +18,19 @@ def on_tpu() -> bool:
 def interpret_mode() -> bool:
     """Pallas kernels run in interpret mode off-TPU (this container is CPU)."""
     return not on_tpu()
+
+
+#: the persistent compile cache's fixed home inside the checkout (gitignored);
+#: a fixed path, because the path is part of the cache key
+COMPILE_CACHE_DIR = Path(__file__).resolve().parents[2] / ".jax_cache"
+
+
+def use_compile_cache() -> None:
+    """Keep compiled programs across processes. Where the environment sets
+    ``JAX_COMPILATION_CACHE_DIR``, JAX reads it itself and nothing here
+    changes; otherwise the cache goes to :data:`COMPILE_CACHE_DIR`."""
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        jax.config.update("jax_compilation_cache_dir", str(COMPILE_CACHE_DIR))
 
 
 def next_pow2(n: int) -> int:
@@ -41,64 +57,32 @@ def pad_to(x: jax.Array, n: int, fill) -> jax.Array:
     )
 
 
+def make_mesh(shape, axis_names):
+    """``jax.make_mesh`` with every axis ``Auto``: arrays are placed by the
+    compiler, so plain gathers and scatters on sharded arrays stay legal
+    (the default ``Explicit`` axes type each array by its sharding and
+    reject them)."""
+    return jax.make_mesh(tuple(shape), tuple(axis_names),
+                         axis_types=(AxisType.Auto,) * len(axis_names))
+
+
 def safe_constrain(x, mesh, spec):
     """with_sharding_constraint that no-ops inside manual (shard_map)
     regions, where the full-mesh NamedSharding is rejected — e.g. the
     pod-compressed gradient path wraps the whole model in a pod-manual
     shard_map; the inner TP constraints become hints we can drop there."""
-    from jax.sharding import NamedSharding
-    try:
-        am = jax.sharding.get_abstract_mesh()
-        if am is not None and any(
-                "Manual" in str(t) for t in getattr(am, "axis_types", ())):
-            return x
-    except Exception:  # noqa: BLE001 — older jax: check the axis env instead
-        # jax<0.5 rejects the constraint only at lowering (uncatchable
-        # here), so pre-check: inside a shard_map, axes are bound in the
-        # axis env — drop the hint if the spec mentions any of them.
-        try:
-            from jax._src.core import get_axis_env
-            bound = set(get_axis_env().axis_sizes)
-        except Exception:  # noqa: BLE001
-            bound = set()
-        named = set()
-        for part in spec:
-            if part is None:
-                continue
-            named |= set(part) if isinstance(part, tuple) else {part}
-        if named & bound:
-            return x
+    if AxisType.Manual in jax.sharding.get_abstract_mesh().axis_types:
+        return x
     try:
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     except ValueError:
         return x
 
 
-def axis_size(axis_name) -> int:
-    """Version-compat static mesh-axis size inside shard_map/pmap bodies
-    (jax<0.5 has no jax.lax.axis_size; psum of the unit constant folds to
-    the static size there)."""
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(axis_name)
-    return jax.lax.psum(1, axis_name)
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_rep=False,
-              axis_names=None):
-    """Version-compat shard_map (jax>=0.8 moved it to jax.shard_map).
-
-    axis_names: axes to run manually (the rest stay auto); None = all.
-    The old experimental API spells that as auto=<complement>.
-    """
-    if hasattr(jax, "shard_map"):
-        kw = {} if axis_names is None else {"axis_names": set(axis_names)}
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_rep, **kw)
-    from jax.experimental.shard_map import shard_map as _sm
-    kw = {} if axis_names is None else {
-        "auto": frozenset(mesh.axis_names) - set(axis_names)}
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=check_rep, **kw)
+def shard_map(f, **kw):
+    """``jax.shard_map`` without the varying-manual-axes check: the engine's
+    per-shard bodies mix shard-local and replicated values freely."""
+    return jax.shard_map(f, check_vma=False, **kw)
 
 
 def tree_bytes(tree) -> int:

@@ -11,15 +11,7 @@ import os
 import subprocess
 import sys
 
-import jax
-import pytest
-
 ROOT = os.path.join(os.path.dirname(__file__), "..")
-
-# partial-manual shard_map (auto=) crashes XLA on jax 0.4.x only — don't
-# blanket-xfail: on jax >= 0.5 the case must actually pass
-_JAX_PRE_05 = tuple(
-    int(x) for x in jax.__version__.split(".")[:2] if x.isdigit()) < (0, 5)
 
 
 def run_case(case: str) -> dict:
@@ -240,11 +232,6 @@ def test_flash_decode_shard_matches_plain():
     assert r["flash_decode_err"] < 2e-4, r
 
 
-@pytest.mark.xfail(
-    condition=_JAX_PRE_05,
-    reason="partial-manual shard_map (auto=) crashes XLA on jax<0.5 — "
-           "pre-existing environment limitation, see ROADMAP open items",
-    strict=False)
 def test_pod_compressed_training_tracks_exact():
     r = run_case("compress_pod")
     # int8 quantization: per-step param drift stays small, loss matches

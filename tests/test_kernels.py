@@ -108,6 +108,30 @@ def test_segment_reduce_tiled_values_land_in_correct_tile():
     np.testing.assert_array_equal(out, expect)
 
 
+@pytest.mark.parametrize("tpu", [False, True])
+def test_segment_reduce_auto_routing_ignores_backend(monkeypatch, tpu):
+    # auto takes the one-hot kernel up to one segment tile and the XLA
+    # scatter beyond it on EVERY backend: past one tile the kernel re-reads
+    # every row per tile (rows x segments). The backend is steered here,
+    # and the executor is a spy, so no kernel runs.
+    import repro.utils
+    from repro.kernels.segment_reduce import MAX_SEGMENTS
+
+    monkeypatch.setattr(repro.utils, "on_tpu", lambda: tpu)
+    picked = []
+
+    def spy(values, seg_ids, num_segments, op, use_kernel):
+        picked.append(use_kernel)
+        return jnp.zeros((num_segments,), values.dtype)
+
+    monkeypatch.setattr(kops, "_segment_reduce_jit", spy)
+    vals = jnp.ones((64,), jnp.float32)
+    seg = jnp.zeros((64,), jnp.int32)
+    for g in (MAX_SEGMENTS, MAX_SEGMENTS + 1, 1 << 20):
+        kops.segment_reduce(vals, seg, g, "sum")
+    assert picked == [True, False, False]
+
+
 # --- segment scan: carry across the row-block (1024) boundary -------------------
 
 

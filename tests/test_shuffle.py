@@ -16,8 +16,9 @@ import pytest
 
 from repro.core import plan as PL
 from repro.core import stats as S
-from repro.core.repartition import (_chunk_bounds, pack_by_partition,
-                                    repartition, staged_all_to_all)
+from repro.core.repartition import (_chunk_bounds, _counts_carrier,
+                                    pack_by_partition, repartition,
+                                    staged_all_to_all)
 from repro.core.table import Table
 from repro.utils import shard_map
 
@@ -125,10 +126,10 @@ def test_repartition_empty_table():
 
 
 def test_repartition_stagings_bit_identical():
-    # "a" sorts before "k": the 2-D float32 payload is the counts carrier,
+    # "a" sorts before "k": the 2-D int32 payload is the counts carrier,
     # exercising the N-D meta-slot pack/unpack
     n = 24
-    t = Table({"a": jnp.arange(2 * n, dtype=jnp.float32).reshape(n, 2) * 0.5,
+    t = Table({"a": jnp.arange(2 * n, dtype=jnp.int32).reshape(n, 2) * 3,
                "k": jnp.arange(n, dtype=jnp.int32)},
               jnp.asarray(n, jnp.int32))
     pid = jnp.zeros((n,), jnp.int32)
@@ -143,6 +144,19 @@ def test_repartition_stagings_bit_identical():
         assert int(rc) == int(rc1) and int(ov) == int(ov1), name
         for col in c1:
             assert bool(jnp.all(c[col] == c1[col])), (name, col)
+
+
+@pytest.mark.parametrize("columns,carrier", [
+    ({"a": jnp.float32, "k": jnp.int32}, "k"),
+    ({"a": jnp.uint32, "k": jnp.int32}, "a"),
+    ({"a": jnp.float32, "b": jnp.uint8}, None),
+])
+def test_counts_carrier_is_a_4byte_integer_column(columns, carrier):
+    # counts bitcast into a float column would be subnormal below 2^23,
+    # and the TPU flushes subnormals to zero: floats never carry them
+    t = Table({name: jnp.zeros((4,), dtype) for name, dtype in columns.items()},
+              jnp.asarray(4, jnp.int32))
+    assert _counts_carrier(t) == carrier
 
 
 def test_repartition_counts_fallback_without_4byte_column():

@@ -115,6 +115,21 @@ def test_analyze_exact_rows_and_idempotence(ctx):
     assert abs(a.stats.col("k").ndv - true_ndv) <= max(4.0, 0.15 * true_ndv)
 
 
+def test_default_mesh_is_auto_and_analyze_runs():
+    # jax.make_mesh defaults to Explicit axes, which type every global
+    # array by its sharding and reject the plain gathers/scatters of the
+    # analyze sweep; the context's default mesh must be Auto
+    from jax.sharding import AxisType
+
+    ctx = DistContext()
+    assert ctx.mesh.axis_types == (AxisType.Auto,)
+    t = Table.from_arrays({"k": np.arange(40, dtype=np.int32) % 7},
+                          capacity=48)
+    a = ctx.analyze(ctx.scatter(t))
+    assert a.stats.rows == 40.0
+    assert a.stats.col("k").lo == 0.0 and a.stats.col("k").hi == 6.0
+
+
 def test_analyze_skips_nd_payload_columns(ctx):
     t = Table.from_arrays({
         "k": np.arange(8, dtype=np.int32),
