@@ -28,18 +28,35 @@ Transport selection (paper §II-D: TCP vs Infiniband) becomes *mesh-axis
 selection*: shuffling over an intra-pod axis rides ICI; an axis that spans
 pods rides DCN. Same operator code, different wire — the paper's
 communication-layer abstraction, preserved.
+
+Host spans. Each query's host work is recorded as
+``jax.profiler.TraceAnnotation`` spans, all with the query's number
+(``query=<n>``, counted per context) so one query's spans can be told from
+another's: ``engine.submit`` (the whole of :meth:`DistContext.submit`)
+holds ``engine.plan`` (optimize or cost model, plus the cache key) and one
+of ``engine.dispatch`` (the jitted call of a plan-cache hit) or
+``engine.compile`` (a miss: trace, compile or persistent-cache load and the
+first enqueue; ``cache=`` names the key's namespace); ``engine.retry``
+(``rung=``) is each recovery rung after the first attempt, and
+``engine.verify`` is a future's finalize (the overflow readback and any
+late retry). The spans are recorded only while a profiler trace runs;
+otherwise each costs one annotation enter and exit.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
+import itertools
 import os
 import threading
+import time
 import weakref
 from typing import Callable, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core import faults as FLT
@@ -269,6 +286,11 @@ class DistContext:
         # in-flight futures with deferred overflow verification; weakly
         # held so an abandoned future never pins its tables
         self._pending: list = []
+        # numbers each submit; every host span of a query carries it
+        self._queries = itertools.count(1)
+        # host seconds spent in dispatches that traced and compiled (or
+        # loaded from the persistent cache): the engine.compile spans
+        self.compile_s = 0.0
         # guards _pending / _overflow_bad / overflow_retries: submit and
         # result() may be called from multiple client threads. Reentrant
         # because a finalize running under it may fold further bookkeeping.
@@ -414,13 +436,16 @@ class DistContext:
         (process-wide; see ``repro.core.verify``), this context's
         recovery-ladder counters (``overflow_retries``,
         ``degraded_kernel``/``degraded_shuffle``, ``compile_retries``,
-        ``generic_retries``, ``quarantines``, ``failed_queries``) and the
-        fault registry's ``fault_calls``/``fault_fires``."""
+        ``generic_retries``, ``quarantines``, ``failed_queries``), the
+        fault registry's ``fault_calls``/``fault_fires``, and ``compile_s``:
+        host seconds spent in dispatches that compiled (plan-cache misses
+        and uncacheable plans)."""
         from repro.core import verify as V
 
         with self._lock:
             rec = dict(self.recovery)
             rec["overflow_retries"] = self.overflow_retries
+            rec["compile_s"] = self.compile_s
         return {**self.plan_cache.stats(), **V.counter_snapshot(),
                 **self.faults.stats(), **rec}
 
@@ -472,13 +497,17 @@ class DistContext:
                 problems.append(f"NaN in column {name!r}")
         return problems
 
-    def _run(self, key, body: Callable, tabs: Sequence[DistTable]):
+    def _run(self, key, body: Callable, tabs: Sequence[DistTable], *,
+             query: int = 0):
         """Execute per-shard `body` over DistTables under shard_map + jit.
 
         ``key`` controls the executable cache: None -> never cached (a
         plan neither canonical- nor content-keyable re-traces per call —
         always correct). The key's own tuples strongly pin any objects
-        whose equality the lookup relies on.
+        whose equality the lookup relies on. After the lookup, the call
+        runs in an ``engine.dispatch`` span on a hit and in an
+        ``engine.compile`` span otherwise, whose seconds add to
+        :attr:`compile_s`.
         """
         global_fn = self._make_global(body)
         args = tuple((t.columns, t.row_counts) for t in tabs)
@@ -499,7 +528,14 @@ class DistContext:
             jitted = jax.jit(global_fn)
         reg = FLT.current()
         fires = reg.fire_count() if reg is not None else 0
-        cols, rc, stats = jitted(*args)  # first call on a miss = the trace
+        t0 = time.perf_counter()
+        with TraceAnnotation("engine.dispatch" if cached else "engine.compile",
+                             query=query,
+                             cache=key[0] if key is not None else "none"):
+            cols, rc, stats = jitted(*args)  # first call on a miss = the trace
+        if not cached:
+            with self._lock:
+                self.compile_s += time.perf_counter() - t0
         poisoned = reg is not None and reg.fire_count() != fires
         if sig is not None and not cached and not poisoned:
             # admit only AFTER a successful fault-free first call: a trace
@@ -562,45 +598,49 @@ class DistContext:
         poison the pending-fold list; ``result()`` re-raises for its
         owner alone.
         """
-        try:
-            with FLT.scope(self.faults):
-                return self._submit_impl(plan, tabs, optimize=optimize,
-                                         report=report)
-        except Exception as e:
-            self._bump("failed_queries")
-            return PlanFuture.failed(e)
+        query = next(self._queries)
+        with TraceAnnotation("engine.submit", query=query):
+            try:
+                with FLT.scope(self.faults):
+                    return self._submit_impl(plan, tabs, optimize=optimize,
+                                             report=report, query=query)
+            except Exception as e:
+                self._bump("failed_queries")
+                return PlanFuture.failed(e)
 
     def _submit_impl(self, plan: PL.Node, tabs: Sequence[DistTable], *,
-                     optimize: bool, report: list | None) -> PlanFuture:
+                     optimize: bool, report: list | None,
+                     query: int) -> PlanFuture:
         p = self.num_shards
         logical = plan
         schemas = [t.schema for t in tabs]
         input_stats = [t.stats for t in tabs]
         have_stats = any(s is not None for s in input_stats)
         policy = self.retry_policy
-        if optimize:
-            plan, part = PL.optimize_with_partitioning(
-                plan, schemas, p, input_stats=input_stats)
-        else:
-            # eager one-node plans skip the logical rewrites but still get
-            # strategy resolution + capacity sizing from the cost model
-            part = PL.output_partitioning(plan, schemas, p)
-            plan = PL.apply_cost_model(plan, schemas, p, input_stats)
-        if isinstance(part, RangePartitioning):
-            # materialized tables get a unique provenance token: two
-            # executions of the same plan shape over different inputs have
-            # different splitters and must never fingerprint-match
-            part = dataclasses.replace(
-                part, fingerprint=fresh_range_fingerprint())
-        key = PL.canonical_key(plan)
-        if key is None:
-            # content-based fallback for keyless user lambdas; None when
-            # the plan cannot be safely keyed (opaque callable, unhashable
-            # capture) — _run then skips the cache entirely
-            ikey = PL.identity_key(plan)
-            run_key = ("plan-id", ikey) if ikey is not None else None
-        else:
-            run_key = ("plan", key)
+        with TraceAnnotation("engine.plan", query=query):
+            if optimize:
+                plan, part = PL.optimize_with_partitioning(
+                    plan, schemas, p, input_stats=input_stats)
+            else:
+                # eager one-node plans skip the logical rewrites but still get
+                # strategy resolution + capacity sizing from the cost model
+                part = PL.output_partitioning(plan, schemas, p)
+                plan = PL.apply_cost_model(plan, schemas, p, input_stats)
+            if isinstance(part, RangePartitioning):
+                # materialized tables get a unique provenance token: two
+                # executions of the same plan shape over different inputs have
+                # different splitters and must never fingerprint-match
+                part = dataclasses.replace(
+                    part, fingerprint=fresh_range_fingerprint())
+            key = PL.canonical_key(plan)
+            if key is None:
+                # content-based fallback for keyless user lambdas; None when
+                # the plan cannot be safely keyed (opaque callable, unhashable
+                # capture) — _run then skips the cache entirely
+                ikey = PL.identity_key(plan)
+                run_key = ("plan-id", ikey) if ikey is not None else None
+            else:
+                run_key = ("plan", key)
         sized = have_stats and PL.plan_cost_sized(plan)
         safe_memo: dict = {}  # the safe plan is derived at most once
 
@@ -622,12 +662,15 @@ class DistContext:
                 v_plan, ns = plan, "plan"
             if FLT.MONO_SHUFFLE in degrade:
                 v_plan = PL.degrade_shuffles(v_plan)
-            v_key = PL.canonical_key(v_plan)
-            if v_key is not None:
-                base = (ns, v_key)
+            if not safe and v_plan is plan:
+                base = run_key  # keyed once, under engine.plan
             else:
-                ik = PL.identity_key(v_plan)
-                base = (ns + "-id", ik) if ik is not None else None
+                v_key = PL.canonical_key(v_plan)
+                if v_key is not None:
+                    base = (ns, v_key)
+                else:
+                    ik = PL.identity_key(v_plan)
+                    base = (ns + "-id", ik) if ik is not None else None
             if base is None:
                 v_run_key = None
             elif degrade:
@@ -643,21 +686,26 @@ class DistContext:
 
             if FLT.ORACLE_KERNEL in degrade:
                 with kops.oracle_scope():
-                    return self._run(v_run_key, body, tabs)
-            return self._run(v_run_key, body, tabs)
+                    return self._run(v_run_key, body, tabs, query=query)
+            return self._run(v_run_key, body, tabs, query=query)
 
         def run_with_recovery(safe: bool, degrade: frozenset = frozenset()):
             """Walk the ladder: execute, classify the failure, degrade
             the next attempt — bounded by the retry policy. Only injected
             ``FaultError``s ride the ladder; genuine programming errors
-            propagate immediately (retrying them is noise)."""
+            propagate immediately (retrying them is noise). Each attempt
+            after the first runs in an ``engine.retry`` span named by its
+            rung."""
             degrade = set(degrade)
-            last = None
+            last = rung = None
             for attempt in range(1, max(1, policy.max_attempts) + 1):
-                if attempt > 1:
-                    policy.sleep(attempt - 1)
+                span = contextlib.nullcontext() if attempt == 1 else \
+                    TraceAnnotation("engine.retry", query=query, rung=rung)
                 try:
-                    out, stats = run_variant(safe, frozenset(degrade))
+                    with span:
+                        if attempt > 1:
+                            policy.sleep(attempt - 1)
+                        out, stats = run_variant(safe, frozenset(degrade))
                     return out, stats, frozenset(degrade)
                 except FLT.FaultError as e:
                     last = e
@@ -698,8 +746,10 @@ class DistContext:
                         self.overflow_retries += 1
                         if run_key is not None:
                             self._overflow_bad.add(run_key)
-                    out, stats, degraded = run_with_recovery(
-                        safe=True, degrade=degraded)
+                    with TraceAnnotation("engine.retry", query=query,
+                                         rung="safe-capacity"):
+                        out, stats, degraded = run_with_recovery(
+                            safe=True, degrade=degraded)
             if self._validation_on():
                 problems = self._validate_result(out, stats, tabs)
                 if problems:
@@ -707,10 +757,12 @@ class DistContext:
                     # fully degraded (oracle kernels + monolithic
                     # shuffles — every rung that changes the program)
                     self._bump("quarantines")
-                    out, stats, degraded = run_with_recovery(
-                        safe=bad_estimates,
-                        degrade=frozenset((FLT.ORACLE_KERNEL,
-                                           FLT.MONO_SHUFFLE)))
+                    with TraceAnnotation("engine.retry", query=query,
+                                         rung="quarantine"):
+                        out, stats, degraded = run_with_recovery(
+                            safe=bad_estimates,
+                            degrade=frozenset((FLT.ORACLE_KERNEL,
+                                               FLT.MONO_SHUFFLE)))
                     problems = self._validate_result(out, stats, tabs)
                     if problems:
                         raise RuntimeError(
@@ -724,7 +776,8 @@ class DistContext:
 
         def finalize():
             try:
-                with FLT.scope(self.faults):
+                with TraceAnnotation("engine.verify", query=query), \
+                        FLT.scope(self.faults):
                     return finalize_inner()
             except Exception:
                 self._bump("failed_queries")

@@ -34,6 +34,13 @@ from repro.core.repartition import (ShuffleStats, _counts_carrier,
 from repro.core.table import Table
 
 
+#: the ``jax.named_scope`` of every exchange: destination ids (hash or
+#: range), pack, the AllToAll stages and unpack. It nests inside the
+#: operator's scope (``plan.OPERATOR_SCOPES``) and takes the exchange's
+#: device time away from that operator.
+EXCHANGE_SCOPE = "engine.exchange"
+
+
 def _row_pid(table: Table, key_columns: Sequence[str], p: int, seed: int):
     pid, _ = L.hash_partition(table, key_columns, p, seed=seed)
     return pid
@@ -83,11 +90,12 @@ def _shuffle(table: Table, keys: Sequence[str], *, axis_name: str,
         })
     if skip:
         return table, zero_shuffle_stats()
-    if pid is None:
-        pid = _row_pid(table, list(keys), p, seed)
-    return repartition(table, pid, axis_name=axis_name,
-                       bucket_capacity=bucket_capacity, stages=stages,
-                       shuffle_mode=shuffle_mode)
+    with jax.named_scope(EXCHANGE_SCOPE):
+        if pid is None:
+            pid = _row_pid(table, list(keys), p, seed)
+        return repartition(table, pid, axis_name=axis_name,
+                           bucket_capacity=bucket_capacity, stages=stages,
+                           shuffle_mode=shuffle_mode)
 
 
 def dist_repartition_by(table: Table, keys: Sequence[str] | str, *,
@@ -231,12 +239,13 @@ def dist_join(
     on_l = [on] if isinstance(on, str) else list(on)
     ps = seed if shuffle_seed is None else shuffle_seed
     lpid = rpid = None
-    if align == "left":
-        rpid = _range_align_pid(right, left, list(align_keys),
-                                axis_name=axis_name)
-    elif align == "right":
-        lpid = _range_align_pid(left, right, list(align_keys),
-                                axis_name=axis_name)
+    with jax.named_scope(EXCHANGE_SCOPE):
+        if align == "left":
+            rpid = _range_align_pid(right, left, list(align_keys),
+                                    axis_name=axis_name)
+        elif align == "right":
+            lpid = _range_align_pid(left, right, list(align_keys),
+                                    axis_name=axis_name)
     left2, st_l = _shuffle(left, on_l, axis_name=axis_name,
                            bucket_capacity=bucket_capacity, seed=ps,
                            skip=skip_left_shuffle, report=report,
@@ -555,8 +564,9 @@ def dist_window(
                           report=report, label="window", stages=stages,
                           shuffle_mode=shuffle_mode)
     else:
-        pid = _lex_splitter_pids(table, keys, axis_name=axis_name,
-                                 samples_per_shard=samples_per_shard)
+        with jax.named_scope(EXCHANGE_SCOPE):
+            pid = _lex_splitter_pids(table, keys, axis_name=axis_name,
+                                     samples_per_shard=samples_per_shard)
         t2, st = _shuffle(table, keys, axis_name=axis_name,
                           bucket_capacity=bucket_capacity, seed=0, pid=pid,
                           report=report, label="window", stages=stages,
@@ -655,8 +665,9 @@ def dist_sort(
                          report=report, label="sort", stages=stages,
                          shuffle_mode=shuffle_mode)
         return L.sort_by(table, by_l), (st,)
-    pid = _lex_splitter_pids(table, by_l, axis_name=axis_name,
-                             samples_per_shard=samples_per_shard)
+    with jax.named_scope(EXCHANGE_SCOPE):
+        pid = _lex_splitter_pids(table, by_l, axis_name=axis_name,
+                                 samples_per_shard=samples_per_shard)
     out, st = _shuffle(table, by_l, axis_name=axis_name,
                        bucket_capacity=bucket_capacity, seed=0, pid=pid,
                        report=report, label="sort", stages=stages,

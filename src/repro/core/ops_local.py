@@ -37,9 +37,10 @@ from repro.kernels import ops as kops
 
 def compact(table: Table, keep: jax.Array) -> Table:
     """Keep rows where `keep & valid`, compacted to the front (stable)."""
-    keep = keep & table.valid_mask()
-    order = jnp.argsort(~keep, stable=True)
-    return table.gather(order, jnp.sum(keep), fill_invalid=False)
+    with jax.named_scope("engine.step.compact"):
+        keep = keep & table.valid_mask()
+        order = jnp.argsort(~keep, stable=True)
+        return table.gather(order, jnp.sum(keep), fill_invalid=False)
 
 
 def select(table: Table, predicate: Callable[[dict], jax.Array]) -> Table:
@@ -112,7 +113,8 @@ def sort_permutation(
 def sort_by(table: Table, by: Sequence[str] | str, *, algorithm: str = "auto") -> Table:
     by = [by] if isinstance(by, str) else list(by)
     perm = sort_permutation(table, by, algorithm=algorithm)
-    return table.gather(perm, table.row_count, fill_invalid=False)
+    with jax.named_scope("engine.step.permute"):
+        return table.gather(perm, table.row_count, fill_invalid=False)
 
 
 def merge(a: Table, b: Table, by: Sequence[str] | str) -> Table:
@@ -301,8 +303,11 @@ def join(
     rk, rperm = _sorted_keys(right, key_r)
     n_l, n_r = left.row_count, right.row_count
 
-    start = jnp.minimum(jnp.searchsorted(rk, lk, side="left"), n_r)
-    end = jnp.minimum(jnp.searchsorted(rk, lk, side="right"), n_r)
+    # the step scopes name the join's row searches and output gathers in
+    # the compiled program's metadata (its operator scope is the plan's)
+    with jax.named_scope("engine.step.search"):
+        start = jnp.minimum(jnp.searchsorted(rk, lk, side="left"), n_r)
+        end = jnp.minimum(jnp.searchsorted(rk, lk, side="right"), n_r)
     l_valid = jnp.arange(c_l) < n_l
     counts = jnp.where(l_valid, end - start, 0)
 
@@ -310,25 +315,28 @@ def join(
     off = jnp.cumsum(counts) - counts
     total = jnp.sum(counts)
     t = jnp.arange(out_capacity)
-    li = jnp.clip(jnp.searchsorted(off, t, side="right") - 1, 0, c_l - 1)
-    j = t - off[li]
-    ri = jnp.clip(start[li] + j, 0, c_r - 1)
-    slot_valid = t < total
+    with jax.named_scope("engine.step.search"):
+        li = jnp.clip(jnp.searchsorted(off, t, side="right") - 1, 0, c_l - 1)
+    with jax.named_scope("engine.step.emit"):
+        j = t - off[li]
+        ri = jnp.clip(start[li] + j, 0, c_r - 1)
+        slot_valid = t < total
 
-    l_orig = lperm[li]
-    r_orig = rperm[ri]
+        l_orig = lperm[li]
+        r_orig = rperm[ri]
 
-    if verify:
-        eq = jnp.ones((out_capacity,), bool)
-        for k in on:
-            eq &= left.columns[k][l_orig] == right.columns[k][r_orig]
-        slot_valid &= eq
+        if verify:
+            eq = jnp.ones((out_capacity,), bool)
+            for k in on:
+                eq &= left.columns[k][l_orig] == right.columns[k][r_orig]
+            slot_valid &= eq
 
     def out_table(l_idx, r_idx, n):
         def take(col, idx, cap):
-            v = col[jnp.clip(idx, 0, cap - 1)]
-            sel = idx.reshape(idx.shape + (1,) * (col.ndim - 1)) >= 0
-            return jnp.where(sel, v, jnp.zeros_like(v))
+            with jax.named_scope("engine.step.emit"):
+                v = col[jnp.clip(idx, 0, cap - 1)]
+                sel = idx.reshape(idx.shape + (1,) * (col.ndim - 1)) >= 0
+                return jnp.where(sel, v, jnp.zeros_like(v))
 
         cols = {}
         for k in left.column_names:

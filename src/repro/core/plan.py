@@ -54,6 +54,14 @@ happens before wide-row work.
 
 The canonicalized plan (:func:`canonical_key`) is the jit-cache key, so a
 pipeline re-collected every training step compiles exactly once.
+
+:func:`execute_plan` traces each operator node under its own
+``jax.named_scope`` (:data:`OPERATOR_SCOPES`), so every instruction of the
+compiled program names, in its ``op_name`` metadata, the operator that
+issued it. Exchanges open ``engine.exchange`` inside their operator
+(``ops_dist``) and the row-reorder steps of the local operators open
+``engine.step.*`` (``ops_local``). Scopes are metadata only: the compiled
+code is the same with or without them.
 """
 from __future__ import annotations
 
@@ -1235,6 +1243,22 @@ def _canon(node: Node, identity: bool = False):
 # ---------------------------------------------------------------------------
 
 
+#: the ``jax.named_scope`` each operator node's own work is traced under;
+#: nodes not listed (Scan, Project, Repartition) issue no work of their own
+#: beyond their exchange, which is scoped ``engine.exchange``
+OPERATOR_SCOPES = {Select: "engine.filter", Join: "engine.join",
+                   GroupBy: "engine.groupby", Sort: "engine.sort",
+                   Window: "engine.window", SetOp: "engine.setop",
+                   Distinct: "engine.distinct", Limit: "engine.limit"}
+
+
+def _operator_scope(node: Node) -> str | None:
+    for cls, name in OPERATOR_SCOPES.items():
+        if isinstance(node, cls):
+            return name
+    return None
+
+
 def execute_plan(plan: Node, tables: Sequence[Table], *, axis_name: str,
                  num_shards: int, report: list | None = None,
                  safe_capacity: bool = False) -> tuple[Table, tuple]:
@@ -1269,7 +1293,15 @@ def execute_plan(plan: Node, tables: Sequence[Table], *, axis_name: str,
         hit = memo.get(id(node))
         if hit is not None:
             return hit
-        out = _exec(node)
+        scope = _operator_scope(node)
+        if scope is None:
+            out = _exec(node)
+        else:
+            # inputs first, so one operator's scope never wraps another's
+            for kid in children(node):
+                run(kid)
+            with jax.named_scope(scope):
+                out = _exec(node)
         memo[id(node)] = out
         return out
 

@@ -965,6 +965,86 @@ def case_verify_audit():
     return out
 
 
+def case_exchange_scopes():
+    """The join's hash exchange is named in the compiled program: every
+    AllToAll, the pack's sort and its histogram kernel sit under
+    ``engine.exchange`` inside ``engine.join``."""
+    import re
+
+    from repro.data.synthetic import random_table
+
+    ctx = _ctx()
+    da = ctx.scatter(random_table(3000, key_range=300, seed=1),
+                     local_capacity=512)
+    db = ctx.scatter(random_table(3000, key_range=300, seed=2),
+                     local_capacity=512)
+    fr = ctx.frame(da).join(ctx.frame(db), on="k")
+    fr.collect()
+    args = tuple((t.columns, t.row_counts) for t in fr._inputs)
+    (key,) = ctx.plan_cache.keys()
+    text = ctx.plan_cache.get(key).lower(*args).compile().as_text()
+    named = {"all_to_all": [], "pack_sort": [], "histogram": []}
+    for line in text.splitlines():
+        m = re.search(r'op_name="([^"]*)"', line)
+        if not m:
+            continue
+        op_name = m.group(1)
+        scopes = re.findall(r"engine\.[a-z]+(?=/|$)", op_name)
+        if " all-to-all(" in line:
+            named["all_to_all"].append(scopes)
+        elif " sort(" in line and "engine.exchange" in op_name:
+            named["pack_sort"].append(scopes)
+        elif "bucket_histogram" in op_name:
+            named["histogram"].append(scopes)
+    return {k: {"count": len(v),
+                "under_join_exchange": all(
+                    s[-2:] == ["engine.join", "engine.exchange"] for s in v)}
+            for k, v in named.items()}
+
+
+def case_overflow_retry_spans():
+    """The late safe-capacity retry of case_overflow_retry's skewed
+    repartition, traced: one ``engine.retry`` span with its rung, inside
+    the ``engine.verify`` of the same query, holding that query's second
+    compile."""
+    import tempfile
+
+    from repro.core.table import Table
+    from repro.testing.spans import engine_spans
+
+    ctx = _ctx()
+    p = ctx.num_shards
+    parts = [Table.from_arrays({
+        "k": np.zeros(400, np.int32),
+        "d0": np.arange(i * 400, (i + 1) * 400).astype(np.float32)})
+        for i in range(p)]
+    dt = ctx.analyze(ctx.from_local_parts(parts))
+    trace_dir = tempfile.mkdtemp()
+    jax.profiler.start_trace(trace_dir)
+    try:
+        ctx.frame(dt).partition_by("k").collect_async().result()
+    finally:
+        jax.profiler.stop_trace()
+    spans = engine_spans(trace_dir)
+    by = {}
+    for name, s, e, st in spans:
+        by.setdefault(name, []).append((s, e, st))
+    (retry,) = by.get("engine.retry", [None])
+    (verify,) = by.get("engine.verify", [None])
+    compiles = by.get("engine.compile", [])
+    return {
+        "retries": ctx.overflow_retries,
+        "retry_spans": len(by.get("engine.retry", [])),
+        "rung": retry[2].get("rung") if retry else None,
+        "one_query": len({st.get("query") for _, _, _, st in spans}) == 1,
+        "inside_verify": bool(retry and verify and verify[0] <= retry[0]
+                              and retry[1] <= verify[1]),
+        "compile_namespaces": sorted(c[2]["cache"] for c in compiles),
+        "retry_compiles": sum(retry[0] <= c[0] and c[1] <= retry[1]
+                              for c in compiles) if retry else 0,
+    }
+
+
 CASES = {k[5:]: v for k, v in list(globals().items())
          if k.startswith("case_")}
 

@@ -216,6 +216,22 @@ def test_async_overflow_verification_is_deferred():
     assert "plan-safe" in r["cache_namespaces"], r
 
 
+def test_join_exchange_is_scoped_inside_the_join():
+    r = run_case("exchange_scopes")
+    for part in ("all_to_all", "pack_sort", "histogram"):
+        assert r[part]["count"] >= 1, r
+        assert r[part]["under_join_exchange"], r
+
+
+def test_overflow_retry_emits_a_retry_span():
+    r = run_case("overflow_retry_spans")
+    assert r["retries"] == 1 and r["retry_spans"] == 1, r
+    assert r["rung"] == "safe-capacity", r
+    assert r["one_query"] and r["inside_verify"], r
+    assert r["compile_namespaces"] == ["plan", "plan-safe"], r
+    assert r["retry_compiles"] == 1, r
+
+
 def test_moe_ep_matches_local():
     r = run_case("moe_ep")
     assert r["moe_ep_err"] < 2e-5, r
