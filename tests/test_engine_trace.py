@@ -4,6 +4,7 @@ import re
 
 import jax
 import numpy as np
+import pytest
 
 from repro.core.context import DistContext
 from repro.core.table import Table
@@ -60,26 +61,36 @@ def _scope(op_name: str) -> str | None:
     return found[-1] if found else None
 
 
-def _tables(ctx):
+def _tables(ctx, right_rows=50):
     rng = np.random.default_rng(3)
     a = Table.from_arrays({
         "k": rng.integers(0, 50, 300).astype(np.int32),
         "x": rng.random(300).astype(np.float32)})
-    b = Table.from_arrays({"k": np.arange(50, dtype=np.int32),
-                           "g": (np.arange(50) % 3).astype(np.int32)})
+    b = Table.from_arrays({"k": np.arange(right_rows, dtype=np.int32),
+                           "g": (np.arange(right_rows) % 3).astype(np.int32)})
     return ctx.scatter(a), ctx.scatter(b)
 
 
-def _query(ctx, da, db):
+def _query(ctx, da, db, out_capacity=None):
     return (ctx.frame(da).select(lambda c: c["x"] > 0.3, key="x>0.3")
-            .join(ctx.frame(db), on="k")
+            .join(ctx.frame(db), on="k", out_capacity=out_capacity)
             .groupby("g", (("x", "sum"),)))
 
 
-def test_compiled_plan_work_carries_operator_scopes():
+#: the join's row searches take the merge and the scatter-max at like-sized
+#: sides; 300 probe rows into 50,000 (output capacity 8) keep all three
+#: scan searches
+SEARCH_SHAPES = {"merge": {}, "scan": {"right_rows": 50_000,
+                                       "out_capacity": 8}}
+
+
+@pytest.mark.parametrize("search", sorted(SEARCH_SHAPES))
+def test_compiled_plan_work_carries_operator_scopes(search):
+    shape = dict(SEARCH_SHAPES[search])
+    out_capacity = shape.pop("out_capacity", None)
     ctx = DistContext()
-    da, db = _tables(ctx)
-    fr = _query(ctx, da, db)
+    da, db = _tables(ctx, **shape)
+    fr = _query(ctx, da, db, out_capacity)
     fr.collect()
     args = tuple((t.columns, t.row_counts) for t in fr._inputs)
     (key,) = ctx.plan_cache.keys()
@@ -90,12 +101,27 @@ def test_compiled_plan_work_carries_operator_scopes():
     assert not unscoped, unscoped
     scopes = {_scope(op) for _, _, op in work}
     assert {"engine.filter", "engine.join", "engine.groupby"} <= scopes
-    # the join's three searchsorted loops run under its search step
     loops = [op for _, w, op in work if "while" in w
              and "searchsorted" in op]
-    assert len(loops) == 3, loops
+    passes = [(w, op) for _, w, op in work
+              if "merge_search" in op or "expand_slots" in op]
+    if search == "scan":
+        # a few probe rows into many: the three searchsorted loops
+        assert len(loops) == 3, loops
+        assert not passes, passes
+        searches = loops
+    else:
+        # start / end from one merge (two sorts and scans), the slot
+        # expansion from one scatter-max and a running max: no loop
+        assert not loops, loops
+        assert sum("sort" in w for w, op in passes
+                   if "merge_search" in op) == 2, passes
+        assert any("scatter" in w for w, op in passes
+                   if "expand_slots" in op), passes
+        assert not any("while" in w for w, _ in passes), passes
+        searches = [op for _, op in passes]
     assert all(_scope(op) == "engine.join" and "engine.step.search" in op
-               for op in loops), loops
+               for op in searches), searches
 
 
 def _inside(inner, outer) -> bool:
