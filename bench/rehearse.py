@@ -6,9 +6,11 @@ No chip is needed: the TPU compiler compiles for the devices of a
 described ``v5e:2x2`` topology. For the cell's generator, its query's
 cost-sized plan (with the statistics ``analyze`` would give at that size)
 and, on several chips, the safe-capacity plan that an overflow retry
-runs, it prints the bytes per device that ``memory_analysis()`` reports
-and whether the compiled text calls a Pallas kernel. A program that does
-not fit the chip fails to compile here.
+runs, it prints the bytes per device that ``memory_analysis()`` reports,
+whether the compiled text calls a Pallas kernel, and the jitted functions
+it calls (``bench.roofline``: the names a ``<kernel>_roofline`` reader
+can read). The kernels compile as on the chip, not in interpret mode. A
+program that does not fit the chip fails to compile here.
 """
 from __future__ import annotations
 
@@ -28,6 +30,7 @@ import numpy as np  # noqa: E402
 from jax.sharding import AxisType, Mesh, NamedSharding  # noqa: E402
 from jax.sharding import PartitionSpec as P  # noqa: E402
 
+from bench import roofline  # noqa: E402
 from bench.run import AXIS, load_cell  # noqa: E402
 
 #: distinct values of each column at full scale (None: as many as rows, so
@@ -56,9 +59,12 @@ def _report(label: str, compiled):
               "temp_size_in_bytes", "generated_code_size_in_bytes")
     vals = {f: int(getattr(mem, f, 0)) for f in fields}
     total = sum(vals.values())
+    calls = sorted({n for ns in roofline.hlo_calls(text).values()
+                    for n in ns})
     print(json.dumps({"program": label, "bytes_per_device": total, **vals,
                       "tpu_custom_call": "tpu_custom_call" in text,
-                      "all_to_all": "all-to-all" in text}), flush=True)
+                      "all_to_all": "all-to-all" in text,
+                      "calls": calls}), flush=True)
 
 
 def main(argv=None) -> int:
@@ -68,12 +74,16 @@ def main(argv=None) -> int:
 
     from jax.experimental import topologies
 
+    import repro.utils
     from repro.core import plan as PL
     from repro.core.context import DistContext, DistTable
     from repro.core.frame import LazyFrame
     from repro.core.serving import ServingSession
 
     jax.config.update("jax_enable_compilation_cache", False)
+    # the kernels decide interpret mode by the backend, which is the CPU
+    # here: compile them as the chip runs them
+    repro.utils.on_tpu = lambda: True
     spec = load_cell(args.workload)
     chips = spec["cell"]["chips"]
     topo = topologies.get_topology_desc(platform="tpu",

@@ -26,6 +26,14 @@ One run, in one process:
    ``--trace 1`` the per-layer metrics read from a profiler trace of the
    window.
 
+A reader is ``read(summary)`` over a :class:`bench.tracing.Summary`: the
+window's device time by opcode category and the harness's spans
+(``bench.tracing``), device time by the engine's operator and step scopes
+and the engine's host spans (``bench.scopes``), and the device seconds
+and logical bytes of each jitted function the programs call, with the
+chip's HBM bandwidth (``bench.roofline``). It returns None where it finds
+nothing to read, and the metric is then left out of the line.
+
 Without a TPU, or with fewer chips than the cell asks for, the run fails
 and prints no result line.
 """
@@ -36,7 +44,9 @@ import time
 T_START = time.perf_counter()
 
 import argparse  # noqa: E402
+import collections  # noqa: E402
 import contextlib  # noqa: E402
+import dataclasses  # noqa: E402
 import functools  # noqa: E402
 import importlib.util  # noqa: E402
 import json  # noqa: E402
@@ -54,7 +64,7 @@ for _p in (str(ROOT / "src"), str(ROOT)):
     if _p not in sys.path:
         sys.path.insert(0, _p)
 
-from bench import tracing  # noqa: E402
+from bench import roofline, scopes, tracing  # noqa: E402
 
 AXIS = "shuffle"
 
@@ -165,14 +175,24 @@ class Run:
         from repro.core.serving import ServingSession
 
         class RecordingCache(PlanCache):
-            """Keeps every program admitted, to read its compiled text."""
+            """Keeps every program admitted, to read its compiled text, and
+            counts the runs of each (by ``id``): the first call that
+            admits it and every hit."""
 
             def __init__(self):
                 super().__init__()
                 self.admitted = []
+                self.runs = collections.Counter()
+
+            def get(self, key):
+                value = super().get(key)
+                if value is not None:
+                    self.runs[id(value)] += 1
+                return value
 
             def put(self, key, value, **kw):
                 self.admitted.append(value)
+                self.runs[id(value)] += 1
                 return super().put(key, value, **kw)
 
         self.mesh = Mesh(np.asarray(self.used), (AXIS,),
@@ -219,18 +239,31 @@ class Run:
         for _ in range(int(self.spec["mix"]["warmup_queries"])):
             jax.block_until_ready(self.one_query())
 
-    def op_categories(self) -> dict[str, dict[str, str]]:
-        """Module name -> instruction categories, from the compiled text
-        of every program the query's plans admitted (each compiled again:
-        a persistent-cache hit)."""
+    def program_maps(self, since: dict[int, int] | None = None) -> dict:
+        """What the programs the query's plans admitted say of their
+        instructions, each traced and compiled again (a persistent-cache
+        hit): per module name, the instruction categories
+        (``categories``), operator scopes (``scopes``) and jitted calls
+        (``calls``); and ``bytes``, the calls' logical bytes per device
+        over the runs since ``since``, a copy of ``plan_cache.runs``."""
         frame = self.query.build(self.session, self.params)
         args = tuple((t.columns, t.row_counts) for t in frame._inputs)
-        out = {}
+        maps = {"categories": {}, "scopes": {}, "calls": {}, "bytes": {}}
+        readers = {"categories": tracing.hlo_categories,
+                   "scopes": scopes.hlo_scopes, "calls": roofline.hlo_calls}
         for jitted in self.plan_cache.admitted:
-            text = jitted.lower(*args).compile().as_text()
+            traced = jitted.trace(*args)
+            text = traced.lower().compile().as_text()
             module = text.split(None, 2)[1].rstrip(",")
-            out.setdefault(module, {}).update(tracing.hlo_categories(text))
-        return out
+            for key, read in readers.items():
+                maps[key].setdefault(module, {}).update(read(text))
+            ran = self.plan_cache.runs[id(jitted)]
+            n = ran - since.get(id(jitted), 0) if since is not None else 0
+            if n:
+                maps["bytes"] = roofline.add_bytes(
+                    maps["bytes"],
+                    roofline.call_bytes(traced.jaxpr, self.chips), n)
+        return maps
 
     def host_inputs(self) -> dict:
         """Host copies of the input columns the reference reads."""
@@ -267,6 +300,27 @@ class _Spans:
             + time.perf_counter() - t0
 
 
+def attribute(summary: tracing.Summary, records: dict, spans: list,
+              maps: dict, hbm_bytes_per_s: float | None) -> tracing.Summary:
+    """``summary`` with the engine's attribution of the same window
+    (``bench.scopes``; its top ops labelled with their operator and step,
+    its idle gaps named by engine spans too) and the seconds and bytes of
+    the jitted functions the programs call (``bench.roofline``) attached.
+    ``maps`` is :meth:`Run.program_maps`."""
+    eng = scopes.summarize(records, spans, summary.queries, maps["scopes"])
+    scope_of = {k: v for sc in maps["scopes"].values() for k, v in sc.items()}
+    labelled = []
+    for op, secs in summary.device_ops:  # "fusion.3 [gather]"
+        names = [n for n in scope_of.get(op.split(" ")[0]) or () if n]
+        labelled.append([f"{op} {'/'.join(names)}" if names else op, secs])
+    return dataclasses.replace(
+        summary, operator_s=eng["operator_s"], step_s=eng["step_s"],
+        engine_span_s=eng["span_s"], scoped_s=eng["scoped_s"],
+        device_ops=labelled, idle_gaps=eng["idle_gaps"],
+        call_s=roofline.call_seconds(records, maps["calls"]),
+        call_bytes=maps["bytes"], hbm_bytes_per_s=hbm_bytes_per_s)
+
+
 def judge(run_: Run, kept: list, host: dict) -> dict:
     """Compare every kept result with the reference: the worst of each
     number over the results, each beside its limit."""
@@ -291,8 +345,11 @@ def passes(checks: dict) -> bool:
 
 
 def execute(args, *, require_tpu: bool = True,
-            config_override: dict | None = None) -> dict:
-    """One run; returns the result line as a dict."""
+            config_override: dict | None = None,
+            traced: dict | None = None) -> dict:
+    """One run; returns the result line as a dict. A trace run leaves
+    its window's ``records``, engine ``spans``, program ``maps`` and
+    ``summary`` in ``traced``, where given."""
     os.environ.setdefault("TPU_LOG_DIR",
                           os.path.join(tempfile.gettempdir(), "tpu_logs"))
     import jax
@@ -313,6 +370,7 @@ def execute(args, *, require_tpu: bool = True,
         f"{k} {v:.6f}" for k, v in run_.spans.seconds.items()))
     ctx = run_.ctx
     before = ctx.cache_stats()
+    runs_before = dict(run_.plan_cache.runs)
 
     trace_dir = None
     if args.trace:
@@ -345,7 +403,9 @@ def execute(args, *, require_tpu: bool = True,
     log(f"window: {len(kept)} queries in {window_s:.6f} s; seconds per "
         f"query: {', '.join(f'{t:.6f}' for t in times)}")
     log(f"compiles in the window (plan-cache misses): "
-        f"{after['misses'] - before['misses']}")
+        f"{after['misses'] - before['misses']}, compile_s "
+        f"{after['compile_s'] - before['compile_s']:.6f}; in set-up: "
+        f"{before['misses']}, compile_s {before['compile_s']:.6f}")
     log("recovery counters: " + ", ".join(
         f"{k} {after[k]}" for k in (
             "overflow_retries", "degraded_kernel", "degraded_shuffle",
@@ -355,7 +415,7 @@ def execute(args, *, require_tpu: bool = True,
         f"HBM)" if run_.peaks else ""
     log(f"peak_bytes_in_use: {peak}{share}")
 
-    categories = run_.op_categories() if args.trace else None
+    maps = run_.program_maps(runs_before) if args.trace else None
     t_check = time.perf_counter()
     host = run_.host_inputs()
     run_.free()
@@ -371,11 +431,18 @@ def execute(args, *, require_tpu: bool = True,
     line = {"correct": correct, "attempted": attempted, "failed": failed}
     if args.trace:
         try:
-            summary = tracing.summarize(tracing.load(trace_dir),
-                                        queries=len(kept_host),
-                                        categories=categories)
+            records = tracing.load(trace_dir)
+            spans = scopes.load_spans(trace_dir)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
+        summary = attribute(
+            tracing.summarize(records, queries=len(kept_host),
+                              categories=maps["categories"]),
+            records, spans, maps,
+            run_.peaks["hbm_bytes_per_s"] if run_.peaks else None)
+        if traced is not None:
+            traced.update(records=records, spans=spans, maps=maps,
+                          summary=summary)
         metrics = {}
         for m in run_.spec["per_layer"]:
             reader = load_module(BENCH / "metrics" / f"{m['name']}.py")
@@ -388,6 +455,13 @@ def execute(args, *, require_tpu: bool = True,
                                "idle_gaps": summary.idle_gaps})
         log(f"trace: busy {summary.busy_s:.6f} s of {summary.window_s:.6f} "
             f"s; device seconds by category: {summary.category_s}")
+        log(f"engine scopes: coverage {summary.coverage:.6f} "
+            f"({summary.scoped_s:.6f} s of busy {summary.busy_s:.6f} s); "
+            f"device seconds by operator: {summary.operator_s}; by step: "
+            f"{summary.step_s}")
+        log("jitted calls (device seconds, bytes per device): " + ", ".join(
+            f"{n} {s:.6f} {summary.call_bytes.get(n)}"
+            for n, s in summary.call_s.items()))
     else:
         values = {"setup_s": setup_s,
                   "rows_per_s": len(kept_host) * run_.rows_scanned

@@ -15,8 +15,8 @@ The engine names its work in two ways (``repro.core``):
 operator and step, beside :func:`bench.tracing.hlo_categories` and from the
 same text. :func:`load_spans` reads the engine's host spans from the trace
 that :func:`bench.tracing.load` reads. :func:`summarize` reduces both over
-the window of a :func:`bench.tracing.load` record, and :func:`metrics`
-gives the per-layer numbers by name.
+the window of a :func:`bench.tracing.load` record; ``bench/run.py``
+attaches the result to the readers' summary.
 
 Attribution rule: an instruction belongs to the innermost operator or
 exchange scope in its ``op_name``, so an exchange inside a join counts as
@@ -42,15 +42,6 @@ UNSCOPED = "unscoped"
 
 _OP_NAME = re.compile(r'op_name="([^"]*)"')
 _SCOPE = re.compile(r"engine\.[\w.]+")
-
-#: per-layer metric -> (operator scope, or host span), read per query
-DEVICE_METRICS = {"filter_ms.batch": "engine.filter",
-                  "join_ms.batch": "engine.join",
-                  "groupby_ms.batch": "engine.groupby",
-                  "exchange_ms.batch": "engine.exchange"}
-HOST_METRICS = {"plan_host_ms.batch": "engine.plan",
-                "dispatch_host_ms.batch": "engine.dispatch"}
-
 
 def scope_of(op_name: str) -> tuple[str | None, str | None]:
     """(operator, step) of one ``op_name``: the innermost operator or
@@ -166,24 +157,3 @@ def summarize(records: dict, spans: list, queries: int,
             "step_s": {k: v / n / 1e9 for k, v in sorted(step_s.items())},
             "span_s": dict(span_s),
             "idle_gaps": sorted(gaps, key=lambda g: -g[1])[:10]}
-
-
-def metrics(summary: dict) -> dict[str, float | None]:
-    """The per-layer numbers by name, in ms per query; None where the
-    scope ran nothing or the span never opened."""
-    q = summary["queries"]
-    out = {}
-    for name, scope in DEVICE_METRICS.items():
-        s = summary["operator_s"].get(scope, 0.0)
-        out[name] = 1e3 * s / q if s and q else None
-    for name, span in HOST_METRICS.items():
-        s = sum(summary["span_s"].get(span, ()))
-        out[name] = 1e3 * s / q if s and q else None
-    return out
-
-
-def coverage(summary: dict) -> float:
-    """Share of the busy device time in which an op of some operator or
-    exchange scope ran."""
-    return summary["scoped_s"] / summary["busy_s"] if summary["busy_s"] \
-        else 0.0

@@ -38,10 +38,10 @@ def waiting_cells(tmp_path_factory, monkeypatch):
                         functools.partial(R.load_cell, root=root))
 
 
-def _execute(cell, seed=2 ** 31 + 7, trace=0):
-    args = R.parse_args(["--workload", cell, "--seed", str(seed),
-                         "--seconds", "0.5", "--trace", str(trace)])
-    return R.execute(args, require_tpu=False, config_override=SMALL[cell])
+def _execute(cell, seed=2 ** 31 + 7, trace=0, run=R):
+    args = run.parse_args(["--workload", cell, "--seed", str(seed),
+                           "--seconds", "0.5", "--trace", str(trace)])
+    return run.execute(args, require_tpu=False, config_override=SMALL[cell])
 
 
 @pytest.mark.parametrize("cell", sorted(SMALL))
@@ -61,6 +61,87 @@ def test_cell_runs_correct(cell, trace):
         assert set(line["metrics"]) == {m["name"] for m in want}
         assert all(m["value"] > 0 for m in line["metrics"].values())
     assert line["device"]["count"] == 4
+
+
+#: per-layer metrics read from the engine's scopes and spans and from the
+#: kernels' bytes and seconds, each with something to read on the CPU
+ATTRIBUTED = ("filter_ms.batch", "join_ms.batch", "groupby_ms.batch",
+              "exchange_ms.batch", "plan_host_ms.batch",
+              "dispatch_host_ms.batch", "hash32_roofline",
+              "bucket_histogram_roofline", "segment_reduce_roofline")
+
+
+def _check_shares(metrics):
+    for n, m in metrics.items():
+        if n.endswith("_roofline"):
+            assert m["unit"] == "%" and 0 < m["value"] <= 100, (n, m)
+
+
+def _v5e_peaks(run, monkeypatch):
+    """The readers divide by a v5e's HBM bandwidth, as on the chip (off
+    the chip a run knows no peak): such a share is no device reading."""
+    attribute = run.attribute
+    bw = run.peaks("TPU v5 lite")["hbm_bytes_per_s"]
+    monkeypatch.setattr(run, "attribute",
+                        lambda *a: attribute(*a[:-1], bw))
+
+
+def test_trace_run_reads_the_engines_names(monkeypatch):
+    _v5e_peaks(R, monkeypatch)
+    line = _execute("q12_sf40_4chip", trace=1)
+    assert line["correct"], line["checks"]
+    got = line["metrics"]
+    missing = [n for n in ATTRIBUTED if n not in got]
+    assert not missing, got
+    assert all(got[n]["value"] > 0 for n in ATTRIBUTED), got
+    _check_shares(got)
+
+
+#: what a later cell brings: its own readers, each one file, over the
+#: engine's scopes or a kernel's roofline
+NEW_READERS = {
+    "groupby_ms.q1": (
+        "def read(summary):\n"
+        "    s = summary.operator_s.get('engine.groupby')\n"
+        "    return 1e3 * s / summary.queries if s else None\n"),
+    "segment_reduce_roofline.q1": (
+        "from bench import roofline\n\n\n"
+        "def read(summary):\n"
+        "    return roofline.share(summary, '_segment_reduce_jit')\n"),
+}
+
+
+def test_new_cell_takes_metrics_with_one_file_and_one_entry(tmp_path,
+                                                           monkeypatch):
+    """A copy of the benchmark gains the waiting ``q1_sf10`` cell and two
+    per-layer metrics by new files and appended entries alone, and its
+    traced run reads both."""
+    shutil.copy(R.ROOT / "BENCHMARK.json", tmp_path)
+    shutil.copytree(R.BENCH, tmp_path / "bench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    spec = json.loads((tmp_path / "BENCHMARK.json").read_text())
+    for k, extra in WAITING.items():
+        spec[k] = spec[k] + extra
+    for name, source in NEW_READERS.items():
+        (tmp_path / "bench" / "metrics" / f"{name}.py").write_text(source)
+        spec["per_layer"].append({
+            "name": name, "unit": "%" if "roofline" in name else "ms",
+            "better": "higher" if "roofline" in name else "lower",
+            "source": "device_trace", "moves": "rows_per_s",
+            "layer": "kernels" if "roofline" in name else "local operators",
+            "workloads": ["q1_sf10"]})
+    (tmp_path / "BENCHMARK.json").write_text(json.dumps(spec))
+    before = json.loads((R.ROOT / "BENCHMARK.json").read_text())
+    assert all(spec[k][:len(v)] == v for k, v in before.items()
+               if isinstance(v, list))
+    copy = R.load_module(tmp_path / "bench" / "run.py")
+    _v5e_peaks(copy, monkeypatch)
+    line = _execute("q1_sf10", trace=1, run=copy)
+    assert line["correct"], line["checks"]
+    got = line["metrics"]
+    assert set(NEW_READERS) <= set(got), got
+    assert all(got[n]["value"] > 0 for n in NEW_READERS)
+    _check_shares(got)
 
 
 def _keep_half(select):

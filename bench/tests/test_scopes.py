@@ -7,7 +7,12 @@ import json
 import pytest
 
 from bench import scopes, tracing
-from bench.run import BENCH, load_module
+from bench.run import BENCH, attribute, load_module
+
+#: the per-layer metrics read from the engine's scopes and spans
+ENGINE_METRICS = ("filter_ms.batch", "join_ms.batch", "groupby_ms.batch",
+                  "exchange_ms.batch", "plan_host_ms.batch",
+                  "dispatch_host_ms.batch")
 
 HLO = "\n".join([
     "HloModule jit_global_fn, is_scheduled=true",
@@ -76,7 +81,6 @@ def test_summary_splits_device_time_and_names_gaps():
         "engine.join": 50e-9, "engine.exchange": 15e-9,
         scopes.UNSCOPED: 10e-9})
     assert s["scoped_s"] == pytest.approx(60e-9)
-    assert scopes.coverage(s) == pytest.approx(60 / 70)
     assert s["step_s"] == pytest.approx({
         "engine.join/engine.step.emit": 20e-9,
         "engine.join/engine.step.search": 30e-9})
@@ -84,7 +88,19 @@ def test_summary_splits_device_time_and_names_gaps():
     # [90,100] under the harness's wait
     assert sorted((n, round(g * 1e9)) for n, g in s["idle_gaps"]) == [
         ("bench.wait", 10), ("engine.submit", 10), ("engine.verify", 10)]
-    m = scopes.metrics(s)
+    # the readers of the engine's names, over the summary they are given
+    summary = attribute(tracing.summarize(rec, queries=1), rec, spans,
+                        {"scopes": {"jit_global_fn": scopes.hlo_scopes(HLO)},
+                         "calls": {}, "bytes": {}}, None)
+    assert summary.coverage == pytest.approx(60 / 70)
+    # the top ops carry their operator and step
+    assert dict(summary.device_ops) == pytest.approx({
+        "while.6 [other] engine.join/engine.step.search": 30e-9,
+        "fusion [other] engine.join/engine.step.emit": 20e-9,
+        "all-to-all.4 [alltoall] engine.exchange": 15e-9,
+        "copy.7 [other]": 10e-9})
+    m = {n: load_module(BENCH / "metrics" / f"{n}.py").read(summary)
+         for n in ENGINE_METRICS}
     assert m["join_ms.batch"] == pytest.approx(50e-6)
     assert m["exchange_ms.batch"] == pytest.approx(15e-6)
     assert m["plan_host_ms.batch"] == pytest.approx(3e-6)
@@ -115,8 +131,7 @@ def test_engine_profile_on_the_cpu(tmp_path):
                               seconds=0.5, out=str(tmp_path / "w.json.gz"))
     line = EP.profile(args, require_tpu=False,
                       config_override={"scale_factor": 0.04})
-    m = line["engine_metrics"]
-    assert set(m) == set(scopes.DEVICE_METRICS) | set(scopes.HOST_METRICS)
+    m = {n: line["metrics"][n] for n in ENGINE_METRICS}
     assert all(v is not None and v > 0 for v in m.values()), m
     assert line["coverage"] > 0.9
     assert "engine.join/engine.step.search" in line["step_s"]

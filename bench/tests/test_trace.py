@@ -111,3 +111,43 @@ def test_peaks_are_keyed_by_device_kind():
     assert peaks("TPU v5 lite")["hbm_bytes_per_s"] == 819e9
     with pytest.raises(BenchError):
         peaks("cpu")
+
+
+def test_device_ops_count_a_loop_once():
+    """A loop's body runs inside the loop's own op: the top ops list the
+    loop alone, while the categories count every op as before. An op
+    that overlaps another without running it is listed on its own."""
+    rec = {"ops": {"/device:TPU:0": [
+        ["%while.1 = s32[8] while(s32[8] %a), body=%b", 10, 90],
+        ["%fusion.2 = s32[8] fusion(s32[8] %b), kind=kLoop", 10, 40],
+        ["%gather.3 = s32[8] gather(s32[8] %b, s32[8] %c)", 50, 90],
+        ["%all-to-all.4 = s32[8] all-to-all(s32[8] %c)", 91, 100],
+        ["%sort.5 = (s32[8]) sort(s32[8] %c)", 95, 98]]},
+        "spans": [["bench.window", 0, 100]]}
+    s = tracing.summarize(rec, queries=1)
+    assert [(n, round(v * 1e9)) for n, v in s.device_ops] == [
+        ("while.1 [other]", 80), ("all-to-all.4 [alltoall]", 9),
+        ("sort.5 [sort]", 3)]
+    assert s.busy_s == pytest.approx(89e-9)
+    assert s.category_s["gather"] == pytest.approx(40e-9)
+    assert s.category_s["other"] == pytest.approx(80e-9)
+
+
+def test_attribution_leaves_the_recorded_readings_alone(recorded):
+    """The seven accepted readers read the same values once the engine's
+    attribution and the calls' seconds are attached to the summary."""
+    from bench.run import attribute
+
+    names = ("device_idle_frac.batch", "sort_ms.batch", "scatter_ms.batch",
+             "gather_ms.batch", "kernel_ms.batch",
+             "alltoall_exposed_ms.batch", "submit_host_ms.batch")
+    readers = {n: load_module(BENCH / "metrics" / f"{n}.py") for n in names}
+    rec = recorded["records"]
+    plain = tracing.summarize(rec, recorded["queries"],
+                              recorded["categories"])
+    maps = {"scopes": {}, "calls": {}, "bytes": {}}
+    attached = attribute(plain, rec, [], maps, 819e9)
+    assert attached.hbm_bytes_per_s == 819e9
+    assert attached.operator_s  # the fixture's ops are all unscoped
+    for n, r in readers.items():
+        assert r.read(attached) == r.read(plain), n

@@ -17,6 +17,7 @@ import bisect
 import collections
 import dataclasses
 import glob
+import math
 import os
 import re
 
@@ -32,6 +33,9 @@ CATEGORIES = (
     ("gather", ("gather",)),
     ("sort", ("sort",)),
 )
+
+#: opcodes whose events hold the events of the computations they run
+CONTAINERS = ("while", "conditional", "call")
 
 _INSTR = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = (.*)$")
 _OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
@@ -62,12 +66,15 @@ def op_name(text: str) -> str:
     return m.group(1) if m else text
 
 
+def _ops_of(text: str) -> set[str]:
+    m = _INSTR.match(text)
+    return _opcodes(m.group(2)) if m else {text.split(".")[0]}
+
+
 def category(text: str) -> str:
     """Category of a device op from its own HLO text alone, or from its
     name (``sort.35``) where the trace gives only that."""
-    m = _INSTR.match(text)
-    return _classify(_opcodes(m.group(2)) if m
-                     else {text.split(".")[0]})
+    return _classify(_ops_of(text))
 
 
 def hlo_categories(hlo_text: str) -> dict[str, str]:
@@ -206,7 +213,12 @@ def subtract(a, b) -> list[tuple[float, float]]:
 
 @dataclasses.dataclass
 class Summary:
-    """The traced window reduced to what the per-layer readers need."""
+    """The traced window reduced to what the per-layer readers need.
+
+    The fields after ``idle_gaps`` are attached by ``bench/run.py`` from
+    the engine's own names (``bench.scopes``) and the jitted functions the
+    programs call (``bench.roofline``); they stay empty where nothing
+    attached them."""
 
     window_s: float
     queries: int                       # queries completed in the window
@@ -217,6 +229,26 @@ class Summary:
     span_s: dict[str, list[float]]     # harness span name -> durations
     device_ops: list                   # [[op name, seconds]], top 10
     idle_gaps: list                    # [[host span, seconds]], top 10
+    # operator or exchange scope -> device seconds, mean over devices
+    operator_s: dict[str, float] = dataclasses.field(default_factory=dict)
+    # "operator/step" scope -> device seconds, mean over devices
+    step_s: dict[str, float] = dataclasses.field(default_factory=dict)
+    # engine host span name -> durations
+    engine_span_s: dict[str, list[float]] = dataclasses.field(
+        default_factory=dict)
+    scoped_s: float = 0.0              # under some operator scope, mean
+    # jitted function -> device seconds in the window, mean over devices
+    call_s: dict[str, float] = dataclasses.field(default_factory=dict)
+    # jitted function -> logical bytes per device in the window (None
+    # where the program does not fix them)
+    call_bytes: dict[str, int | None] = dataclasses.field(
+        default_factory=dict)
+    hbm_bytes_per_s: float | None = None   # the chip's peak, peaks.json
+
+    @property
+    def coverage(self) -> float:
+        """Share of the busy device time under some operator scope."""
+        return self.scoped_s / self.busy_s if self.busy_s else 0.0
 
 
 def _window(records: dict) -> tuple[int, int]:
@@ -277,12 +309,18 @@ def summarize(records: dict, queries: int,
         merged = union(((s, e) for _, s, e in evs), lo, hi)
         busy += covered(merged)
         by_cat = collections.defaultdict(list)
-        for text, s, e in evs:
+        outer_end = -math.inf
+        for text, s, e in sorted(evs, key=lambda r: (r[1], -r[2])):
             name = op_name(text)
             known = categories.get(_module_of(modules, s), {}) if modules \
                 else anywhere
             cat = known.get(name) or category(text)
             by_cat[cat].append((s, e))
+            # a loop's body runs inside the loop's own op, which counts it
+            if e <= outer_end:
+                continue
+            if _ops_of(text) & set(CONTAINERS):
+                outer_end = e
             d = min(e, hi) - max(s, lo)
             if d > 0:
                 per_op[f"{name} [{cat}]"] += d
