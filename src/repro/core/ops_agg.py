@@ -5,13 +5,19 @@ Data Aggregations", arXiv:2010.14596) treats keyed aggregation as the
 workhorse of distributed data engineering. The local algorithm here is the
 sort-based path adapted to the compacted-front Table invariant:
 
-    sort-by-key  ->  segment-boundary detection  ->  segment reductions
+    sort keys  ->  segment-boundary detection  ->  segment reductions
+
+The sort carries the keys and a row index, never the payload: each row's
+group id is carried back to where the row lies, and the reductions read
+the payload columns in place.
 
 Exact multi-column keys throughout (the sort compares real key columns, as
 in ops_local's sort path); hashing appears only as the distributed
 pre-partitioner (ops_dist.dist_groupby). The segment reductions run on the
 Pallas one-hot kernel (kernels/segment_reduce.py) for the hot 1-D shapes
-and on XLA scatter-reduce otherwise — identical semantics.
+and on XLA otherwise — the same semantics, with float sums accurate on
+both (``kops.segment_reduce``), each under the ``engine.step.reduce``
+scope.
 
 Aggregators: sum / count / min / max / mean / var / first. Every aggregator
 decomposes into *algebraic* partials (sum, sumsq, count, min, max, first)
@@ -81,27 +87,36 @@ def _prim_name(col: str, prim: str) -> str:
 
 
 def _segments(table: Table, keys: Sequence[str]):
-    """Sort by keys -> (sorted table, seg_id (cap,) int32 [-1 invalid],
-    num_groups, starts (cap,) int32 row index of each group's first row)."""
+    """Group the rows by keys without moving them -> (table, seg_id (cap,)
+    int32 in the table's own row order [-1 invalid], num_groups, starts
+    (cap,) int32 row index of each group's first row).
+
+    One stable sort of (invalid, keys, row index) numbers the groups in key
+    order; a second sort, by that row index, carries each row's group id
+    back to the row. The payload columns are never reordered: the
+    segment reductions take rows in any order."""
     if table.capacity == 0:
         table = Table({k: jnp.zeros((1,) + v.shape[1:], v.dtype)
                        for k, v in table.columns.items()}, table.row_count)
-    st = L.sort_by(table, list(keys))
-    cap = st.capacity
-    valid = st.valid_mask()
+    cap = table.capacity
+    rows = jnp.arange(cap, dtype=jnp.int32)
+    invalid = (~table.valid_mask()).astype(jnp.int32)
+    out = jax.lax.sort((invalid, *(table.columns[k] for k in keys), rows),
+                       num_keys=1 + len(keys), is_stable=True)
+    valid, skeys, perm = out[0] == 0, out[1:-1], out[-1]
     differs = jnp.zeros((cap,), bool)
-    for k in keys:
-        col = st.columns[k]
+    for col in skeys:
         differs = differs | (col != jnp.roll(col, 1))
-    boundary = valid & (differs | (jnp.arange(cap) == 0))
+    boundary = valid & (differs | (rows == 0))
     seg = jnp.cumsum(boundary.astype(jnp.int32)) - 1
     seg = jnp.where(valid, seg, -1)
     num_groups = jnp.sum(boundary).astype(jnp.int32)
     # one boundary row per group: scatter its row index to slot seg[i]
     starts = jnp.zeros((cap,), jnp.int32).at[
-        jnp.where(boundary, seg, cap)].set(jnp.arange(cap, dtype=jnp.int32),
-                                           mode="drop")
-    return st, seg, num_groups, starts
+        jnp.where(boundary, seg, cap)].set(perm, mode="drop")
+    with jax.named_scope("engine.step.permute"):
+        _, seg = jax.lax.sort((perm, seg), num_keys=1)
+    return table, seg, num_groups, starts
 
 
 def _first(col: jax.Array, starts: jax.Array, group_valid: jax.Array):
@@ -115,19 +130,27 @@ def _first(col: jax.Array, starts: jax.Array, group_valid: jax.Array):
 def _reduce(col: jax.Array, seg: jax.Array, slots: int, prim: str,
             group_valid: jax.Array, use_kernel):
     """One algebraic partial over a (cap, ...) column -> (slots, ...)."""
-    if prim == "sumsq":
-        col = col.astype(jnp.float32) ** 2
-        prim = "sum"
-    out = kops.segment_reduce(col, seg, slots, prim, use_kernel=use_kernel)
+    with jax.named_scope("engine.step.reduce"):
+        if prim == "sumsq":
+            col = col.astype(jnp.float32) ** 2
+            prim = "sum"
+        out = kops.segment_reduce(col, seg, slots, prim,
+                                  use_kernel=use_kernel)
     # empty slots hold the op identity (e.g. +inf for min): zero them so
     # rows past row_count stay benign garbage
     sel = group_valid.reshape((-1,) + (1,) * (out.ndim - 1))
     return jnp.where(sel, out, jnp.zeros_like(out))
 
 
+def _truncated(num_groups: jax.Array, slots: int) -> jax.Array:
+    """int32 count of groups past the ``slots`` a groupby reduced into."""
+    return jnp.maximum(num_groups - slots, 0).astype(jnp.int32)
+
+
 def _partial_columns(table: Table, keys: Sequence[str], pairs, *,
                      out_capacity: int | None = None, use_kernel=None):
-    """Shared phase-1 machinery: per-group key values + algebraic partials.
+    """Shared phase-1 machinery: per-group key values + algebraic partials,
+    and the count of groups that did not fit (:func:`_truncated`).
 
     Reductions run into ``out_capacity`` slots when given (groups past it
     truncate, mirroring join's explicit memory-budget failure mode) — a
@@ -158,7 +181,7 @@ def _partial_columns(table: Table, keys: Sequence[str], pairs, *,
         else:
             cols[name] = _reduce(st.columns[col], seg, slots, prim,
                                  group_valid, use_kernel)
-    return Table(cols, row_count)
+    return Table(cols, row_count), _truncated(num_groups, slots)
 
 
 def _finalize(partial: Table, keys: Sequence[str], pairs) -> Table:
@@ -185,42 +208,54 @@ def _finalize(partial: Table, keys: Sequence[str], pairs) -> Table:
 
 
 def groupby(table: Table, keys: Sequence[str] | str, aggs, *,
-            out_capacity: int | None = None, use_kernel=None) -> Table:
+            out_capacity: int | None = None, use_kernel=None,
+            with_overflow: bool = False):
     """Local GroupBy: one output row per distinct key tuple, ordered by key.
 
     keys: 1-D key column name(s) (exact multi-column comparison).
     aggs: {col: op | [ops]} or [(col, op), ...]; ops in AGG_OPS. N-D payload
     columns support sum/min/max/mean/first (element-wise per row-vector).
     Output columns: keys + ``{col}_{op}``; row_count = number of groups.
+    ``with_overflow``: also return an int32 scalar counting the groups the
+    ``out_capacity`` budget truncated (0 = exact), as ``ops_local.join``
+    does for its rows.
     """
     keys = [keys] if isinstance(keys, str) else list(keys)
     pairs = normalize_aggs(aggs)
-    partial = _partial_columns(table, keys, pairs, out_capacity=out_capacity,
-                               use_kernel=use_kernel)
-    return _finalize(partial, keys, pairs)
+    partial, trunc = _partial_columns(table, keys, pairs,
+                                      out_capacity=out_capacity,
+                                      use_kernel=use_kernel)
+    out = _finalize(partial, keys, pairs)
+    return (out, trunc) if with_overflow else out
 
 
 def partial_groupby(table: Table, keys: Sequence[str] | str, aggs, *,
-                    out_capacity: int | None = None, use_kernel=None) -> Table:
+                    out_capacity: int | None = None, use_kernel=None,
+                    with_overflow: bool = False):
     """Phase 1 of the two-phase strategy: per-shard algebraic partials.
 
     Output rows are one per locally-distinct key (<= key cardinality, the
     shuffle-volume win); columns are the mangled partial slots + keys.
+    ``with_overflow`` as in :func:`groupby`.
     """
     keys = [keys] if isinstance(keys, str) else list(keys)
     pairs = normalize_aggs(aggs)
-    return _partial_columns(table, keys, pairs, out_capacity=out_capacity,
-                            use_kernel=use_kernel)
+    out, trunc = _partial_columns(table, keys, pairs,
+                                  out_capacity=out_capacity,
+                                  use_kernel=use_kernel)
+    return (out, trunc) if with_overflow else out
 
 
 def combine_groupby(partials: Table, keys: Sequence[str] | str, aggs, *,
-                    out_capacity: int | None = None, use_kernel=None) -> Table:
+                    out_capacity: int | None = None, use_kernel=None,
+                    with_overflow: bool = False):
     """Phase 2: merge partial rows that share a key, then finalize.
 
     ``combine_groupby(partial_groupby(t, ...), ...) == groupby(t, ...)`` —
     and partials arriving from different shards (via repartition) combine
     the same way: sums add, min/max re-reduce, first takes the earliest
     partial in row order (repartition preserves source-shard order).
+    ``with_overflow`` as in :func:`groupby`.
     """
     keys = [keys] if isinstance(keys, str) else list(keys)
     pairs = normalize_aggs(aggs)
@@ -242,8 +277,8 @@ def combine_groupby(partials: Table, keys: Sequence[str] | str, aggs, *,
         else:
             cols[name] = _reduce(st.columns[name], seg, slots, comb,
                                  group_valid, use_kernel)
-    merged = Table(cols, row_count)
-    return _finalize(merged, keys, pairs)
+    out = _finalize(Table(cols, row_count), keys, pairs)
+    return (out, _truncated(num_groups, slots)) if with_overflow else out
 
 
 # ---------------------------------------------------------------------------

@@ -353,6 +353,7 @@ def dist_groupby(
     seed: int = 7,
     shuffle_seed: int | None = None,
     skip_shuffle: bool = False,
+    count_truncation: bool = False,
     report: list | None = None,
     stages: int | None = None,
     shuffle_mode: str = "alltoall",
@@ -375,32 +376,48 @@ def dist_groupby(
     ``partial_capacity`` optionally trims the phase-1 partial table (must
     bound the per-shard key cardinality; overflow truncates like join).
     Both strategies produce identical results: one global row per key.
+
+    ``count_truncation``: fold the groups that ``partial_capacity`` and
+    ``out_capacity`` truncated into the ShuffleStats overflow, as
+    :func:`dist_join` does — set by the plan executor where the cost
+    model sized them from a key-cardinality *estimate*, so an
+    underestimate takes the overflow-retry path.
     """
     keys_l = [keys] if isinstance(keys, str) else list(keys)
     pairs = A.normalize_aggs(aggs)
     ps = seed if shuffle_seed is None else shuffle_seed
+    trunc = []
+
+    def local(fn, t, capacity):
+        out, dropped = fn(t, keys_l, pairs, out_capacity=capacity,
+                          with_overflow=True)
+        trunc.append(dropped)
+        return out
+
     if skip_shuffle:
         _, st = _shuffle(table, keys_l, axis_name=axis_name,
                          bucket_capacity=bucket_capacity, seed=ps, skip=True,
                          report=report, label=f"groupby.{strategy}",
                          stages=stages, shuffle_mode=shuffle_mode)
-        return A.groupby(table, keys_l, pairs, out_capacity=out_capacity), (st,)
-    if strategy == "shuffle":
+        out = local(A.groupby, table, out_capacity)
+    elif strategy == "shuffle":
         t2, st = _shuffle(table, keys_l, axis_name=axis_name,
                           bucket_capacity=bucket_capacity, seed=ps,
                           report=report, label="groupby.shuffle",
                           stages=stages, shuffle_mode=shuffle_mode)
-        return A.groupby(t2, keys_l, pairs, out_capacity=out_capacity), (st,)
-    if strategy == "two_phase":
-        part = A.partial_groupby(table, keys_l, pairs,
-                                 out_capacity=partial_capacity)
+        out = local(A.groupby, t2, out_capacity)
+    elif strategy == "two_phase":
+        part = local(A.partial_groupby, table, partial_capacity)
         part2, st = _shuffle(part, keys_l, axis_name=axis_name,
                              bucket_capacity=bucket_capacity, seed=ps,
                              report=report, label="groupby.two_phase",
                              stages=stages, shuffle_mode=shuffle_mode)
-        return A.combine_groupby(part2, keys_l, pairs,
-                                 out_capacity=out_capacity), (st,)
-    raise ValueError(strategy)
+        out = local(A.combine_groupby, part2, out_capacity)
+    else:
+        raise ValueError(strategy)
+    if count_truncation:
+        st = st._replace(overflow=st.overflow + sum(trunc))
+    return out, (st,)
 
 
 def _fold_window_carry(gathered, by, order_by, p: int, k_of):

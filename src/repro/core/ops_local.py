@@ -43,11 +43,24 @@ from repro.kernels import ops as kops
 
 
 def compact(table: Table, keep: jax.Array) -> Table:
-    """Keep rows where `keep & valid`, compacted to the front (stable)."""
+    """Keep rows where `keep & valid`, compacted to the front (stable).
+
+    One stable sort on the drop flag carries the 1-D columns along, so no
+    row gather runs; an N-D column is gathered by the row order the same
+    sort carries."""
     with jax.named_scope("engine.step.compact"):
         keep = keep & table.valid_mask()
-        order = jnp.argsort(~keep, stable=True)
-        return table.gather(order, jnp.sum(keep), fill_invalid=False)
+        flat = [k for k, v in table.columns.items() if v.ndim == 1]
+        wide = [k for k in table.columns if k not in flat]
+        ops = [(~keep).astype(jnp.int32)] + [table.columns[k] for k in flat]
+        if wide:
+            ops.append(jnp.arange(table.capacity, dtype=jnp.int32))
+        out = jax.lax.sort(tuple(ops), num_keys=1, is_stable=True)
+        cols = dict(zip(flat, out[1:]))
+        for k in wide:
+            cols[k] = table.columns[k][out[-1]]
+        return Table({k: cols[k] for k in table.columns},
+                     jnp.sum(keep).astype(jnp.int32))
 
 
 def select(table: Table, predicate: Callable[[dict], jax.Array]) -> Table:

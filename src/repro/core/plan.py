@@ -191,6 +191,8 @@ class GroupBy(Node):
     shuffle_seed: int | None = None
     skip_shuffle: bool = False
     sized: bool = False  # bucket filled in by the cost model (estimate!)
+    out_sized: bool = False  # out/partial capacities from the key NDV, as
+    # Join.out_sized: truncation counts toward the overflow retry
     stages: int | None = None  # shuffle pipeline depth (None = cost pick)
     shuffle_mode: str = "alltoall"
 
@@ -841,17 +843,40 @@ def _pick_node_stages(node: Node, est: _Estimator, p: int, bucket,
     return S.pick_stages(p * p * bucket * rb, bucket)
 
 
+def _size_groupby(node: GroupBy, strategy: str, cs: S.TableStats,
+                  ndv: float, p: int) -> dict:
+    """Output slots of a groupby from its key NDV, on every mesh size.
+
+    A shard that groups its own rows holds at most ``min(ndv, its rows)``
+    groups; after an exchange the keys are hashed over ``p`` shards. Each
+    budget takes the join's margin (``S.with_skew_margin`` /
+    ``S.size_output``) and is marked ``out_sized``: the executor counts
+    the groups past it, so an underestimate takes the safe retry. A
+    capacity the user set is kept as it is, with its truncation contract.
+    """
+    local = S.with_skew_margin(min(ndv, cs.shard_rows(p)))
+    if p == 1 or node.skip_shuffle:
+        return {"out_capacity": local, "out_sized": True}
+    out = {"out_capacity": S.size_output(ndv, p), "out_sized": True}
+    if strategy == "two_phase":
+        out["partial_capacity"] = local
+    return out
+
+
 def _apply_costs(node: Node, est: _Estimator, p: int) -> Node:
     """Fill unset capacities / resolve ``auto`` strategies from estimates.
 
-    Every capacity this pass writes is marked ``sized=True`` on its node:
-    the runtime treats overflow on a sized plan as "the estimate was
-    wrong" and retries once with conservative capacities
+    Every capacity this pass writes is marked ``sized``/``out_sized`` on
+    its node: the runtime treats overflow on a sized plan as "the estimate
+    was wrong" and retries once with conservative capacities
     (``execute_plan(..., safe_capacity=True)``). A single-shard mesh
-    skips sizing entirely — there is no wire to save and the fallback
-    capacities are already local-only. The same pass picks each shuffle's
-    pipeline depth (``stages``) from its estimated wire bytes — S=1 below
-    the threshold, so small shuffles pay zero extra collectives.
+    sizes no exchange bucket and no join output — there is no wire to
+    save and the fallback capacities are already local-only — but does
+    size a groupby's output from its key NDV (:func:`_size_groupby`),
+    which keeps a low-cardinality groupby within the segment kernel. The
+    same pass picks each shuffle's pipeline depth (``stages``) from its
+    estimated wire bytes — S=1 below the threshold, so small shuffles pay
+    zero extra collectives.
     """
     kids = [_apply_costs(c, est, p) for c in children(node)]
     if isinstance(node, GroupBy):
@@ -878,8 +903,13 @@ def _apply_costs(node: Node, est: _Estimator, p: int) -> Node:
             sized = True
         stages = _pick_node_stages(node, est, p, bucket, node.skip_shuffle,
                                    node.child)
+        sizes = {}
+        if (node.out_capacity is None and node.partial_capacity is None
+                and ndv is not None):
+            sizes = _size_groupby(node, strategy, cs, ndv, p)
         return replace(node, child=kids[0], strategy=strategy,
-                       bucket_capacity=bucket, sized=sized, stages=stages)
+                       bucket_capacity=bucket, sized=sized, stages=stages,
+                       **sizes)
     if isinstance(node, Repartition):
         cs = est.stats(node.child)
         bucket, sized = node.bucket_capacity, node.sized
@@ -1373,7 +1403,8 @@ def execute_plan(plan: Node, tables: Sequence[Table], *, axis_name: str,
                 partial_capacity=node.partial_capacity,
                 out_capacity=node.out_capacity, seed=node.seed,
                 shuffle_seed=node.shuffle_seed,
-                skip_shuffle=node.skip_shuffle, report=report,
+                skip_shuffle=node.skip_shuffle,
+                count_truncation=node.out_sized, report=report,
                 stages=node.stages, shuffle_mode=node.shuffle_mode)
             stats.extend(st)
             return out
@@ -1493,7 +1524,9 @@ def explain(plan: Node, input_schemas: Sequence[dict] | None = None,
         if bucket is not None and not isinstance(node, (Select, Project,
                                                         Limit, Scan)):
             parts.append(f"bucket={bucket}")
-        if isinstance(node, Join) and node.out_capacity is not None:
+        if isinstance(node, GroupBy) and node.partial_capacity is not None:
+            parts.append(f"partial={node.partial_capacity}")
+        if isinstance(node, (Join, GroupBy)) and node.out_capacity is not None:
             parts.append(f"out={node.out_capacity}")
         stages = getattr(node, "stages", None)
         if stages is not None:

@@ -37,7 +37,7 @@ __all__ = [
 
 # -- the kernel -> XLA-oracle degradation rung -------------------------------
 # DistContext's recovery ladder re-executes a failed plan with every Pallas
-# segment kernel swapped for its bit-identical XLA oracle. The flag is
+# segment kernel swapped for its XLA oracle. The flag is
 # thread-local and consulted at TRACE time (resolution below happens
 # outside the inner jits, so it always takes effect — a cached trace of
 # the kernel path cannot shadow it).
@@ -53,8 +53,8 @@ def oracle_only() -> bool:
 @contextmanager
 def oracle_scope():
     """Force every segment kernel to its XLA oracle on this thread — the
-    ``oracle-kernel`` recovery rung (bit-identical on the integer-valued
-    inputs the engine produces)."""
+    ``oracle-kernel`` recovery rung (bit-identical on integer-valued
+    inputs; float sums within a few roundings on both routes)."""
     _oracle.depth = getattr(_oracle, "depth", 0) + 1
     try:
         yield
@@ -113,8 +113,13 @@ def segment_reduce(
     The Pallas one-hot kernel handles 1-D f32/i32 values at ANY segment
     count — counts past MAX_SEGMENTS tile the segment axis in a second
     grid dimension (kernels/segment_reduce.py). N-D payloads fall back to
-    XLA scatter-reduce; ``use_kernel=False`` forces that path (the
-    bit-identical oracle the tests sweep against).
+    XLA; ``use_kernel=False`` forces that path (the oracle the tests sweep
+    against). Integer sums, min and max are exact on both routes, so the
+    two agree bit for bit on integer-valued inputs. A float sum is
+    accurate on both whatever a segment's row count: its error grows as
+    the log of the rows, not with them (the kernel's compensated tree,
+    :func:`_float_segment_sum` on XLA), so the routes agree to a few
+    roundings, not bit for bit.
 
     Auto (``use_kernel=None``) takes the kernel only for single-tile
     segment counts (<= MAX_SEGMENTS), on every backend: each further
@@ -147,14 +152,45 @@ def segment_reduce(
 def _segment_reduce_jit(values, seg_ids, num_segments, op, use_kernel):
     if use_kernel:
         return segment_reduce_tiles(values, seg_ids, num_segments, op)
-    init = ref.seg_init(op, values.dtype)
-    out = jnp.full((num_segments,) + values.shape[1:], init, values.dtype)
-    # out-of-range ids -> num_segments, dropped by the scatter
+    # out-of-range ids -> num_segments, dropped below
     idx = jnp.where((seg_ids >= 0) & (seg_ids < num_segments),
                     seg_ids, num_segments)
+    if op == "sum" and jnp.issubdtype(values.dtype, jnp.floating):
+        return _float_segment_sum(values, idx, num_segments)
+    init = ref.seg_init(op, values.dtype)
+    out = jnp.full((num_segments,) + values.shape[1:], init, values.dtype)
     at = out.at[idx]
     scatter = {"sum": at.add, "min": at.min, "max": at.max}[op]
     return scatter(values, mode="drop")
+
+
+def _float_segment_sum(values, idx, num_segments):
+    """Float segment sums whose error grows as the log of a segment's rows.
+
+    A scatter-add adds each segment's rows one after another (on the TPU a
+    sorted scatter), so its sum stalls once the addend falls below half an
+    ulp of the running total. Here the rows are sorted by segment, summed
+    by a segmented tree scan (``associative_scan``: each prefix is
+    O(log n) additions deep), and each segment's last prefix is its sum.
+    """
+    n = idx.shape[0]
+    if values.ndim == 1:
+        idx, values = jax.lax.sort((idx, values), num_keys=1)
+    else:
+        idx, order = jax.lax.sort((idx, jnp.arange(n, dtype=jnp.int32)),
+                                  num_keys=1)
+        values = values[order]
+    same = (slice(None),) + (None,) * (values.ndim - 1)
+
+    def combine(a, b):
+        (ia, va), (ib, vb) = a, b
+        return ib, jnp.where((ia == ib)[same], va + vb, vb)
+
+    _, sums = jax.lax.associative_scan(combine, (idx, values))
+    last = jnp.ones((n,), bool).at[:-1].set(idx[1:] != idx[:-1])
+    out = jnp.zeros((num_segments,) + values.shape[1:], values.dtype)
+    return out.at[jnp.where(last, idx, num_segments)].set(
+        sums, mode="drop", unique_indices=True)
 
 
 def segment_scan(

@@ -183,3 +183,72 @@ def test_retry_rung_gets_a_span(tmp_path):
     retries = [s for s in engine_spans(str(tmp_path))
                if s[0] == "engine.retry"]
     assert [s[3]["rung"] for s in retries] == ["oracle-kernel"]
+
+
+def _reduce_op_names(text: str) -> list[str]:
+    """op_name of every instruction a segment reduction issued."""
+    return [op for op in _OP_NAME.findall(text)
+            if "jit(_segment_reduce_jit)" in op]
+
+
+def test_segment_reductions_carry_the_reduce_step():
+    from repro.core import ops_agg as A
+
+    rng = np.random.default_rng(5)
+    t = Table.from_arrays({"k": rng.integers(0, 4, 200).astype(np.int32),
+                           "x": rng.random(200).astype(np.float32)})
+    aggs = (("x", "sum"), ("x", "max"), ("x", "count"))
+
+    def one_pass(cols, rc):  # the XLA route
+        return A.groupby(Table(cols, rc), "k", aggs, out_capacity=8,
+                         use_kernel=False).columns
+
+    def two_phase(cols, rc):  # the kernel route
+        part = A.partial_groupby(Table(cols, rc), "k", aggs, out_capacity=8)
+        return A.combine_groupby(part, "k", aggs, out_capacity=8).columns
+
+    for fn in (one_pass, two_phase):
+        text = jax.jit(fn).lower(t.columns, t.row_count).compile().as_text()
+        ops = _reduce_op_names(text)
+        assert ops, fn.__name__
+        assert all("engine.step.reduce" in op for op in ops), ops
+    # in a plan, the step sits inside the groupby's operator scope
+    ctx = DistContext()
+    fr = ctx.frame(ctx.scatter(t)).groupby("k", aggs)
+    fr.collect()
+    args = tuple((x.columns, x.row_counts) for x in fr._inputs)
+    (key,) = ctx.plan_cache.keys()
+    text = ctx.plan_cache.get(key).lower(*args).compile().as_text()
+    ops = _reduce_op_names(text)
+    assert ops and all("engine.groupby/engine.step.reduce" in op
+                       for op in ops), ops
+
+
+_ROWS = re.compile(r"^\s*(?:ROOT )?%?([\w.\-]+) = \(?[a-z0-9]+\[(\d+)")
+
+
+def test_filter_and_groupby_move_rows_by_sorts_not_gathers():
+    # the filter's compaction and the groupby's grouping move whole rows
+    # only by sorts: no gather in either scope yields a row-sized array
+    rng = np.random.default_rng(9)
+    n = 512
+    t = Table.from_arrays({"k": rng.integers(0, 4, n).astype(np.int32),
+                           "d": rng.integers(0, 1000, n).astype(np.int32),
+                           "x": rng.random(n).astype(np.float32),
+                           "y": rng.random(n).astype(np.float32)})
+    ctx = DistContext()
+    fr = (ctx.frame(ctx.analyze(ctx.scatter(t)))
+          .select(lambda c: c["d"] < 900, key="d<900")
+          .groupby("k", (("x", "sum"), ("y", "mean"), ("x", "count"))))
+    fr.collect()
+    args = tuple((x.columns, x.row_counts) for x in fr._inputs)
+    (key,) = ctx.plan_cache.keys()
+    text = ctx.plan_cache.get(key).lower(*args).compile().as_text()
+    rows = {m.group(1): int(m.group(2))
+            for m in map(_ROWS.match, text.splitlines()) if m}
+    work = [(name, w, op) for name, w, op in _entry_work(text)
+            if _scope(op) in ("engine.filter", "engine.groupby")]
+    assert any("sort" in w for _, w, _ in work)
+    wide = [(name, op) for name, w, op in work
+            if "gather" in w and rows.get(name, 0) >= n]
+    assert not wide, wide

@@ -283,3 +283,85 @@ def test_concat_empty_then_groupby():
     b = Table.from_arrays(cols, capacity=6)
     out = A.groupby(concat_tables(a, b), "k", [("v", "sum"), ("v", "count")])
     check_vs_oracle(out, cols, ["k"], [("v", "sum"), ("v", "count")])
+
+
+# --- TPC-H Q1's shape through the serving path, sized from statistics ---------
+
+Q1_KEYS = ["returnflag", "linestatus"]
+Q1_AGGS = [("quantity", "sum"), ("extendedprice", "sum"),
+           ("disc_price", "sum"), ("charge", "sum"), ("quantity", "mean"),
+           ("extendedprice", "mean"), ("discount", "mean"),
+           ("quantity", "count")]
+
+
+def q1_table(n, seed=0):
+    """A lineitem-shaped table at Q1's magnitudes (3 x 2 flag codes)."""
+    rng = np.random.default_rng(seed)
+    qty = rng.integers(1, 51, n).astype(np.float32)
+    price = np.round(qty * rng.uniform(900.0, 2100.0, n), 2)
+    disc = rng.integers(0, 11, n) / 100.0
+    tax = rng.integers(0, 9, n) / 100.0
+    return {"returnflag": rng.integers(0, 3, n).astype(np.int32),
+            "linestatus": rng.integers(0, 2, n).astype(np.int32),
+            "shipdate": rng.integers(0, 2526, n).astype(np.int32),
+            "quantity": qty,
+            "extendedprice": price.astype(np.float32),
+            "discount": disc.astype(np.float32),
+            "disc_price": (price * (1 - disc)).astype(np.float32),
+            "charge": (price * (1 - disc) * (1 + tax)).astype(np.float32)}
+
+
+def test_q1_through_serving_matches_oracle():
+    from repro.core.context import DistContext
+    from repro.core.serving import ServingSession
+
+    cols = q1_table(1 << 18)
+    session = ServingSession(DistContext())
+    session.register("lineitem", Table.from_arrays(cols), analyze=True)
+    last = 2436
+    got = (session.frame("lineitem")
+           .select(lambda c: c["shipdate"] <= last, key=("q1<=", last))
+           .groupby(Q1_KEYS, Q1_AGGS).collect().to_table().to_numpy())
+    keep = cols["shipdate"] <= last
+    # the oracle sums the same float32 values in float64
+    kept = {k: (v[keep].astype(np.float64) if v.dtype == np.float32
+                else v[keep]) for k, v in cols.items()}
+    want = groupby_oracle(kept, Q1_KEYS, Q1_AGGS)
+    assert len(got["returnflag"]) == 6
+    for k in Q1_KEYS + ["quantity_count"]:
+        np.testing.assert_array_equal(got[k], want[k], err_msg=k)
+    for name, w in want.items():
+        if name not in Q1_KEYS + ["quantity_count"]:
+            np.testing.assert_allclose(got[name], w, rtol=1e-6, atol=0,
+                                       err_msg=name)
+
+
+def _with_ndv(dt, ndv):
+    """``dt`` whose statistics claim ``ndv`` distinct keys ``k``."""
+    import dataclasses
+
+    from repro.core import stats as S
+
+    stats = S.TableStats(rows=dt.stats.rows,
+                         columns=(("k", S.ColumnStats(float(ndv))),),
+                         max_shard_rows=dt.stats.max_shard_rows)
+    return dataclasses.replace(dt, stats=stats)
+
+
+def test_sized_groupby_underestimate_retries_once_safe():
+    from repro.core.context import DistContext
+
+    ctx = DistContext()
+    cols, t = make_table(600, 200, pad=0, seed=3)
+    aggs = (("v", "sum"), ("v", "count"))
+    dt = _with_ndv(ctx.analyze(ctx.scatter(t)), 2)
+    frame = ctx.frame(dt).groupby("k", aggs)
+    plan = frame.optimized()
+    assert plan.out_sized and plan.out_capacity < 200
+    got = frame.collect()
+    assert ctx.overflow_retries == 1
+    check_vs_oracle(got.to_table(), cols, ["k"], aggs)
+    # a capacity the user set truncates, with no retry
+    cut = ctx.frame(dt).groupby("k", aggs, out_capacity=5).collect()
+    assert ctx.overflow_retries == 1
+    assert int(cut.global_rows()) == 5

@@ -132,6 +132,42 @@ def test_segment_reduce_auto_routing_ignores_backend(monkeypatch, tpu):
     assert picked == [True, False, False]
 
 
+# --- segment reduce: float32 sums stay accurate however long a segment ------
+
+
+def _rel_err(got, want):
+    return float(np.max(np.abs(np.asarray(got, np.float64) - want)
+                        / np.abs(want)))
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_segment_reduce_float_sum_does_not_stall(use_kernel):
+    # 2**24 and then 2**20 ones in one segment: a sum that adds the rows
+    # one after another stays at 2**24 (each 1 is half an ulp there)
+    vals = np.concatenate([[2.0 ** 24], np.ones(2 ** 20)]).astype(np.float32)
+    seg = np.zeros(vals.shape, np.int32)
+    got = kops.segment_reduce(jnp.asarray(vals), jnp.asarray(seg), 4, "sum",
+                              use_kernel=use_kernel)
+    want = vals.astype(np.float64).sum()
+    assert _rel_err(np.asarray(got)[:1], want) <= 1e-6
+    np.testing.assert_array_equal(np.asarray(got)[1:], 0.0)
+
+
+@pytest.mark.parametrize("use_kernel", [True, False])
+def test_segment_reduce_float_sum_q1_magnitudes(use_kernel):
+    # 2**21 shuffled rows in 4 groups at TPC-H Q1's extendedprice scale:
+    # each group's float32 sum within 1e-6 of the float64 sum
+    rng = np.random.default_rng(17)
+    n = 2 ** 21
+    group = rng.integers(0, 4, n).astype(np.int32)
+    price = np.round(rng.integers(1, 51, n) * rng.uniform(900.0, 2100.0, n),
+                     2).astype(np.float32)
+    got = kops.segment_reduce(jnp.asarray(price), jnp.asarray(group), 4,
+                              "sum", use_kernel=use_kernel)
+    want = np.bincount(group, weights=price.astype(np.float64), minlength=4)
+    assert _rel_err(got, want) <= 1e-6
+
+
 # --- segment scan: carry across the row-block (1024) boundary -------------------
 
 

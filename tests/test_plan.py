@@ -571,15 +571,66 @@ def test_cost_sizing_fills_unset_capacities_only():
 
 
 def test_cost_sizing_skipped_on_single_shard():
-    # p == 1: no wire to save; capacities stay at the local defaults so a
-    # stats-tagged table executes byte-identically to an untagged one
+    # p == 1: no wire to save; buckets and join outputs stay at the local
+    # defaults. Only a groupby's output is sized, from its key NDV (the
+    # groups the one shard can hold), and marked out_sized
     plan = PL.GroupBy(PL.Scan(0), ("k",), (("d0", "sum"),))
     o = PL.optimize(plan, [ORDERS], 1, [LO_STATS])
     assert o.strategy == "two_phase" and not o.sized
     assert o.bucket_capacity is None
+    assert o.out_sized and o.out_capacity == S.with_skew_margin(32.0)
+    assert o.partial_capacity is None
     jp = PL.Join(PL.Scan(0), PL.Scan(1), ("k",))
     oj = PL.optimize(jp, [ORDERS, USERS], 1, [HI_STATS, HI_STATS])
     assert not PL.plan_cost_sized(oj)
+
+
+def test_groupby_sized_from_ndv_on_every_mesh():
+    plan = PL.GroupBy(PL.Scan(0), ("k",), (("d0", "sum"),))
+    # two-phase over 8 shards: each shard's partial holds all 32 keys at
+    # most, the combined keys hash over the 8 shards
+    o = PL.optimize(plan, [ORDERS], 8, [LO_STATS])
+    assert o.out_sized
+    assert o.partial_capacity == S.with_skew_margin(32.0)
+    assert o.out_capacity == S.size_output(32.0, 8)
+    # raw shuffle: only the output after the exchange
+    o = PL.optimize(plan, [ORDERS], 8, [HI_STATS])
+    assert o.strategy == "shuffle" and o.partial_capacity is None
+    assert o.out_capacity == S.size_output(7000.0, 8) and o.out_sized
+    # a capacity the user set is deliberate truncation: nothing sized
+    for kw in ({"out_capacity": 9}, {"partial_capacity": 9}):
+        o = PL.optimize(PL.GroupBy(PL.Scan(0), ("k",), (("d0", "sum"),),
+                                   **kw), [ORDERS], 8, [LO_STATS])
+        assert not o.out_sized and o.sized
+        assert PL.cost_sized_stats_mask(o) == [True]  # the bucket alone
+    # no stats: nothing sized
+    o = PL.optimize(plan, [ORDERS], 1)
+    assert o.out_capacity is None and not o.out_sized
+    txt = PL.explain(PL.optimize(plan, [ORDERS], 8, [LO_STATS]),
+                     [ORDERS], [LO_STATS])
+    assert f"out={S.size_output(32.0, 8)}" in txt and "partial=" in txt
+
+
+def test_analyzed_one_chip_groupby_reduces_in_the_kernel(ctx, monkeypatch):
+    from repro.kernels import ops as kops
+
+    routes = []
+    jit = kops._segment_reduce_jit
+
+    def spy(values, seg_ids, num_segments, op, use_kernel):
+        routes.append((num_segments, use_kernel))
+        return jit(values, seg_ids, num_segments, op, use_kernel)
+
+    monkeypatch.setattr(kops, "_segment_reduce_jit", spy)
+    t = ctx.analyze(ctx.scatter(int_table(5000, 6, 11)))
+    frame = ctx.frame(t).groupby("k", (("d0", "sum"), ("d1", "mean")))
+    plan = frame.optimized()
+    assert ctx.num_shards == 1
+    assert plan.out_sized and plan.out_capacity <= kops.MAX_SEGMENTS
+    assert f"out={plan.out_capacity}" in frame.explain()
+    frame.collect()
+    assert routes and all(r == (plan.out_capacity, True) for r in routes), \
+        routes
 
 
 def test_cost_sizing_leaves_aligned_join_bucket_alone():
